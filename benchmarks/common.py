@@ -11,19 +11,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import configure_compile_cache
+
 # Persistent compilation cache for every bench entry point importing this
 # module: the quick CI suites are compile-dominated (tens of seconds of
 # XLA work for seconds of compute), and the jitted steps are identical
 # run to run — cached executables cut reruns to the actual measurement.
-# Opt out (or redirect) with JAX_COMPILATION_CACHE_DIR; the thresholds
-# are zeroed so even the small CPU executables of --quick runs cache.
-_CACHE_DIR = os.environ.get(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.expanduser("~"), ".cache", "jax_repro_bench"))
-if _CACHE_DIR:
-    jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+# JAX_COMPILATION_CACHE_DIR redirects it (empty: off); otherwise it sits
+# at a fixed path inside the checkout.
+configure_compile_cache()
 
 from repro.core.mapping import (map_kmeans, map_naive_bayes, map_svm,
                                 map_tree_ensemble)

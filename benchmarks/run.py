@@ -12,17 +12,19 @@ emitters — ``kernel_microbench`` (BENCH_kernels.json), ``stream_bench``
 ``batch_bench`` (BENCH_batch.json), ``scenario_bench``
 (BENCH_scenarios.json) and ``analysis_bench`` (BENCH_analysis.json,
 the device resource-fit trajectory) — are separate entry points with
-their own gating oracles; ``--all-suites`` runs them here too, so one
-command refreshes the whole trajectory. A failing sub-suite fails the
-whole run immediately (its exit code is propagated), so a broken oracle
-can never leave CI green.
+their own gating oracles; ``--all-suites`` runs them here too, in this
+process, so one command refreshes the whole trajectory. A failing
+sub-suite fails the whole run immediately (its exit code is
+propagated), so a broken oracle can never leave CI green.
 """
 
 from __future__ import annotations
 
 import argparse
+import importlib
 import sys
 import time
+import traceback
 
 from benchmarks.common import write_bench_json
 
@@ -39,30 +41,39 @@ BENCHES = [
 ]
 
 # the standalone bench-v1 emitters --all-suites chains after the in-process
-# benches; each must force its own environment (e.g. shard_stream_bench's
-# multi-device host platform) before its first jax import, hence subprocesses
+# benches, each through its own main(argv) in this process
 EXTRA_SUITES = ("kernel_microbench", "stream_bench", "shard_stream_bench",
                 "batch_bench", "scenario_bench", "latency_bench",
                 "obs_bench", "analysis_bench")
 
 
 def run_suites(suite_modules, quick=False):
-    """Run each standalone emitter as ``python -m benchmarks.<mod>``.
+    """Run each standalone emitter in this process: ``benchmarks.<mod>
+    .main(argv)``.
 
-    Exits the process with the child's return code on the FIRST failure —
-    the exit codes of these subprocesses used to be swallowed into an
-    end-of-run summary only, so an oracle failure in one suite could
-    leave a caller that only checked "did it finish" green. Fail fast
-    and propagate instead.
+    One process, because on a chip host the process that first touches
+    JAX holds the chip: a child started after the in-process benches
+    could not reach it. A suite that needs several host devices on CPU
+    gets them from the caller's ``XLA_FLAGS`` (CI's sharded step sets
+    it); ``shard_stream_bench`` sizes its mesh sweep to the devices
+    present. Exits the process on the FIRST failure — a raised exception
+    (exit 1) or a nonzero ``SystemExit`` (its code) — so an oracle
+    failure in one suite can never leave a caller that only checks "did
+    it finish" green.
     """
-    import subprocess
     for mod_name in suite_modules:
         print(f"\n{'=' * 70}\nbenchmarks.{mod_name}\n{'=' * 70}",
               flush=True)
-        cmd = [sys.executable, "-m", f"benchmarks.{mod_name}"]
-        if quick:
-            cmd.append("--quick")
-        rc = subprocess.run(cmd).returncode
+        rc = 0
+        try:
+            mod = importlib.import_module(f"benchmarks.{mod_name}")
+            mod.main(["--quick"] if quick else [])
+        except SystemExit as e:
+            rc = e.code
+        except Exception:  # noqa: BLE001 — suite boundary: report and
+            #                fail the whole run with the traceback shown
+            traceback.print_exc()
+            rc = 1
         if rc:
             print(f"benchmarks.{mod_name} FAILED (exit {rc})",
                   file=sys.stderr, flush=True)
@@ -100,7 +111,6 @@ def main(argv=None):
             entry["rows"] = mod.run(n=n)
             print(f"[{mod_name}: {time.time() - t0:.1f}s]")
         except Exception:   # keep the suite going; report at the end
-            import traceback
             traceback.print_exc()
             failures.append(mod_name)
             entry["ok"] = False
@@ -120,11 +130,7 @@ def main(argv=None):
               f"{len(failures)} failures {failures}")
         sys.exit(1)
     if args.all_suites:
-        # fresh subprocesses, not in-process main() calls: jax is already
-        # initialized here, and shard_stream_bench must force its
-        # multi-device host platform *before* the first jax import —
-        # in-process it would silently degrade to a 1-device scaling axis.
-        # run_suites exits nonzero on the first failing child.
+        # run_suites exits nonzero on the first failing suite
         run_suites(EXTRA_SUITES, quick=args.quick)
     print(f"\ntotal: {time.time() - t_all:.1f}s; 0 failures")
     sys.exit(0)

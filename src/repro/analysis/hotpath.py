@@ -338,15 +338,14 @@ def _selftest_collectives() -> List[Finding]:
     """Seeded census violations must be caught: extra psums, extra
     reduce_scatters (wrong count), and a rank-1 scatter masquerading as
     the rank>=2 lane-slab merge (wrong rank)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices()[:1]), ("shard",))
     out: List[Finding] = []
 
     def chatty(x):
         return jax.lax.psum(jax.lax.psum(x, "shard"), "shard")
-    fn = jax.jit(shard_map(chatty, mesh=mesh, in_specs=(P(),),
-                           out_specs=P()))
+    fn = jax.jit(jax.shard_map(chatty, mesh=mesh, in_specs=(P(),),
+                               out_specs=P()))
     jaxpr = JU.closed_jaxpr(fn, jnp.zeros((4, 4), jnp.float32))
     census = JU.collective_census(jaxpr)
     if census != {"psum": 1}:
@@ -359,8 +358,8 @@ def _selftest_collectives() -> List[Finding]:
         s = jax.lax.psum_scatter(x, "shard", scatter_dimension=0, tiled=True)
         return jax.lax.psum_scatter(s, "shard", scatter_dimension=0,
                                     tiled=True)
-    fn = jax.jit(shard_map(double_scatter, mesh=mesh, in_specs=(P(),),
-                           out_specs=P(), check_rep=False))
+    fn = jax.jit(jax.shard_map(double_scatter, mesh=mesh, in_specs=(P(),),
+                               out_specs=P(), check_vma=False))
     jaxpr = JU.closed_jaxpr(fn, jnp.zeros((4, 4), jnp.float32))
     census = JU.collective_census(jaxpr)
     if census.get("reduce_scatter") != 1:
@@ -374,8 +373,8 @@ def _selftest_collectives() -> List[Finding]:
     def vector_scatter(x):
         return jax.lax.psum_scatter(x, "shard", scatter_dimension=0,
                                     tiled=True)
-    fn = jax.jit(shard_map(vector_scatter, mesh=mesh, in_specs=(P(),),
-                           out_specs=P(), check_rep=False))
+    fn = jax.jit(jax.shard_map(vector_scatter, mesh=mesh, in_specs=(P(),),
+                               out_specs=P(), check_vma=False))
     jaxpr = JU.closed_jaxpr(fn, jnp.zeros((8,), jnp.float32))
     got = _readout_scatter_count(jaxpr)
     if got != 1:

@@ -25,7 +25,7 @@ from typing import Dict, Iterator, List, Sequence, Set
 
 import jax
 import jax.numpy as jnp
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 # Normalized primitive names that perform cross-device communication.
 # pbroadcast is deliberately absent: shard_map inserts it as replication
@@ -96,7 +96,7 @@ def jaxpr_dtypes(jaxpr) -> Set[str]:
         closed = jaxpr
         jaxpr = jaxpr.jaxpr
         for const in closed.consts:
-            aval = jax_core.get_aval(const)
+            aval = jax.typeof(const)
             if hasattr(aval, "dtype"):
                 dtypes.add(str(aval.dtype))
 

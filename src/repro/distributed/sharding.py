@@ -28,7 +28,7 @@ from typing import Any, Optional
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def _axis(mesh: Mesh, name: str) -> int:
@@ -192,7 +192,8 @@ def flow_shard_mesh(n_shards: Optional[int] = None,
     partitions, not tensor parallelism.
     """
     n = n_shards or max(1, jax.local_device_count() // n_data)
-    return jax.make_mesh((n, n_data), ("shard", "data"))
+    return jax.make_mesh((n, n_data), ("shard", "data"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def as_flow_mesh(mesh: Mesh) -> Mesh:
@@ -201,15 +202,21 @@ def as_flow_mesh(mesh: Mesh) -> Mesh:
     A legacy 1D ('shard',) mesh gains a size-1 'data' axis (same
     devices, same shard blocks), so every shard_map body can reference
     both axes unconditionally; a 2D ('shard', 'data') mesh passes
-    through. Anything else is not a flow-table mesh.
+    through. Anything else is not a flow-table mesh. The result has Auto
+    axes whatever the input had: the streaming tier leaves the placement
+    of everything outside its ``shard_map`` bodies to the compiler, which
+    Explicit axes (``jax.make_mesh``'s default) forbid.
     """
-    if mesh.axis_names == ("shard", "data"):
-        return mesh
     if mesh.axis_names == ("shard",):
-        return Mesh(mesh.devices.reshape(-1, 1), ("shard", "data"))
-    raise ValueError(
-        f"flow-table mesh must have axes ('shard',) or ('shard', 'data'), "
-        f"got {mesh.axis_names}")
+        devices = mesh.devices.reshape(-1, 1)
+    elif mesh.axis_names == ("shard", "data"):
+        devices = mesh.devices
+    else:
+        raise ValueError(
+            f"flow-table mesh must have axes ('shard',) or ('shard', 'data'), "
+            f"got {mesh.axis_names}")
+    return Mesh(devices, ("shard", "data"),
+                axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def flow_table_sharding(mesh: Mesh, state_tree):

@@ -27,7 +27,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.artifact import flatten_vtable
 from repro.kernels.ensemble_lookup import _blocked_one_hot, _range_match
-from repro.kernels.tuning import DEFAULT_TILES, resolve_interpret
+from repro.kernels.tuning import (DEFAULT_TILES, EXACT_F32,
+                                  resolve_interpret)
 
 TILE_N = DEFAULT_TILES.tile_n
 EDGE_CHUNK = DEFAULT_TILES.edge_chunk
@@ -42,7 +43,8 @@ def _fused_classical_kernel(x_ref, edges_ref, vtab_ref, out_ref, *,
     bins = _range_match(x, edges_ref, u_total, edge_chunk)
     oh = _blocked_one_hot(bins, b_pad)                      # (TN, F*Bp)
     out_ref[...] = jax.lax.dot(oh, vtab_ref[...],
-                               preferred_element_type=jnp.float32)
+                               preferred_element_type=jnp.float32,
+                               precision=EXACT_F32)
 
 
 def classical_lookup_fused(x, edges, vtable_flat, *, interpret=None,

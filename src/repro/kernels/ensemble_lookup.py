@@ -49,7 +49,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.artifact import build_dtable_flat, flatten_ftable, pad_dtable
-from repro.kernels.tuning import DEFAULT_TILES, resolve_interpret
+from repro.kernels.tuning import (DEFAULT_TILES, EXACT_F32,
+                                  resolve_interpret)
 
 TILE_N = DEFAULT_TILES.tile_n
 EDGE_CHUNK = DEFAULT_TILES.edge_chunk
@@ -105,7 +106,8 @@ def _match_agg(keys_i, dflat_ref, dtable_chunk):
         dflat = dflat_ref[:, :, lo:hi].reshape(cout, t * (hi - lo))
         out = out + jax.lax.dot_general(
             match, dflat, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (TN, Co)
+            preferred_element_type=jnp.float32,
+            precision=EXACT_F32)                            # (TN, Co)
     return out
 
 
@@ -122,7 +124,8 @@ def _fused_kernel(x_ref, edges_ref, ftab_ref, dflat_ref, out_ref, *,
     # the matmul performs all F lookups AND the mixed-radix key combine.
     oh = _blocked_one_hot(bins, b_pad)                      # (TN, F*Bp)
     keys = jax.lax.dot(oh, ftab_ref[...],
-                       preferred_element_type=jnp.float32)  # (TN, Tp)
+                       preferred_element_type=jnp.float32,
+                       precision=EXACT_F32)                 # (TN, Tp)
     keys_i = keys[:, :t_logical].astype(jnp.int32)          # exact below 2^24
 
     # stages 4+5 as one more matmul: select + aggregate
@@ -146,7 +149,8 @@ def _fused_compare_kernel(x_ref, edges_ref, ftab_ref, dtable_ref, out_ref, *,
     bins = _range_match(x, edges_ref, u_total, edge_chunk)
     oh = _blocked_one_hot(bins, b_pad)                      # (TN, F*Bp)
     keys = jax.lax.dot(oh, ftab_ref[...],
-                       preferred_element_type=jnp.float32)  # (TN, Tp)
+                       preferred_element_type=jnp.float32,
+                       precision=EXACT_F32)                 # (TN, Tp)
     keys_i = keys[:, :t_logical].astype(jnp.int32)
 
     leaf = jnp.zeros((tn, t_logical), jnp.float32)
@@ -159,7 +163,8 @@ def _fused_compare_kernel(x_ref, edges_ref, ftab_ref, dtable_ref, out_ref, *,
         leaf = leaf + jnp.sum(jnp.where(match, dt[None, :, :], 0.0), axis=2)
 
     if vote:
-        c_iota = jax.lax.broadcasted_iota(jnp.float32, (1, 1, n_classes), 2)
+        c_iota = jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, n_classes), 2).astype(jnp.float32)
         out_ref[...] = jnp.sum(
             (leaf[:, :, None] == c_iota).astype(jnp.float32), axis=1)
     else:
@@ -263,7 +268,8 @@ def _loop_kernel(x_ref, edges_ref, ftable_ref, strides_ref, dtable_ref,
         oh = (bins[:, fi][:, None] == b_iota).astype(jnp.float32)  # (TN, B)
         ft = ftable_ref[fi].astype(jnp.float32)             # (B, T)
         code = jax.lax.dot(oh, ft,
-                           preferred_element_type=jnp.float32)     # (TN, T)
+                           preferred_element_type=jnp.float32,
+                           precision=EXACT_F32)             # (TN, T)
         keys = keys + code * strides_ref[:, fi].astype(jnp.float32)[None, :]
     keys_i = keys.astype(jnp.int32)
 
@@ -278,7 +284,8 @@ def _loop_kernel(x_ref, edges_ref, ftable_ref, strides_ref, dtable_ref,
         leaf = leaf + jnp.sum(jnp.where(match, dt[None, :, :], 0.0), axis=2)
 
     if vote:
-        c_iota = jax.lax.broadcasted_iota(jnp.float32, (1, 1, n_classes), 2)
+        c_iota = jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, n_classes), 2).astype(jnp.float32)
         votes = jnp.sum((leaf[:, :, None] == c_iota).astype(jnp.float32),
                         axis=1)                             # (TN, C)
         out_ref[...] = votes

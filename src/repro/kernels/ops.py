@@ -33,13 +33,10 @@ from repro.kernels import evict as _ev
 from repro.kernels import classical_lookup as _ck
 from repro.kernels import ref as _ref
 from repro.kernels import stream_update as _su
-from repro.kernels.tuning import DEFAULT_TILES, TileConfig, padded_rows
+from repro.kernels.tuning import (DEFAULT_TILES, TileConfig, padded_rows,
+                                  resolve_use_pallas)
 
 VMEM_BUDGET_BYTES = 8 * 1024 * 1024   # half of a v5e core's ~16MB VMEM
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_batch(x, tile):
@@ -91,9 +88,7 @@ def evict_fill(regs, mask, fills, *, use_pallas=None, interpret=None):
     """
     regs = jnp.asarray(regs, jnp.float32)
     fills = jnp.asarray(fills, jnp.float32)
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if not use_pallas:
+    if not resolve_use_pallas(use_pallas):
         return jnp.where(mask[None, :], fills[:, None], regs)
     r, n = regs.shape
     tile = min(_ev.TILE_B, n) if n % _ev.TILE_B else _ev.TILE_B
@@ -121,9 +116,7 @@ def stream_update(regs, bucket, ts, length, is_fwd, valid, *, limit=None,
     integer-exactness/associativity argument in the kernel docstring.
     """
     regs = jnp.asarray(regs, jnp.float32)
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if not use_pallas:
+    if not resolve_use_pallas(use_pallas):
         return _ref.stream_update_ref(regs, bucket, ts, length, is_fwd,
                                       valid, limit=limit)
     r, n = regs.shape
@@ -139,9 +132,7 @@ def stream_update(regs, bucket, ts, length, is_fwd, valid, *, limit=None,
 
 def bucketize(x, edges, *, use_pallas=None):
     """Public bucketize. x (N, F), edges (F, U) -> (N, F) int32."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if not use_pallas:
+    if not resolve_use_pallas(use_pallas):
         return _ref.bucketize_ref(x, edges)
     xp, n = _pad_batch(jnp.asarray(x, jnp.float32), _bk.TILE_N)
     return _bk.bucketize_pallas(xp, edges)[:n]
@@ -249,6 +240,19 @@ def _classical_epilogue(art: TableArtifact, out):
     raise ValueError(art.agg)
 
 
+def classify_impl(art: TableArtifact, *, use_pallas=None,
+                  tiles: TileConfig = None) -> str:
+    """The realization ``fused_classify`` runs for this artifact:
+    ``tiles.impl`` ('fused' by default, or 'loop') when the Pallas
+    kernels are on and the tables fit the VMEM budget, else 'ref' (the
+    XLA gather reference). The one place the routing is decided, so a
+    caller can see when a TPU run would leave the kernel."""
+    tiles = tiles or DEFAULT_TILES
+    if resolve_use_pallas(use_pallas) and fits_vmem(art):
+        return tiles.impl
+    return "ref"
+
+
 def classify_batch_rows(art: TableArtifact, n: int, *, use_pallas=None,
                         tiles: TileConfig = None) -> int:
     """Rows ``fused_classify`` actually processes for an n-row batch.
@@ -259,10 +263,8 @@ def classify_batch_rows(art: TableArtifact, n: int, *, use_pallas=None,
     per-device classify work (the shard bench's classify_rows_per_device
     gate) count the kernel's real row count, not the logical one.
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
     tiles = tiles or DEFAULT_TILES
-    impl = tiles.impl if (use_pallas and fits_vmem(art)) else "ref"
+    impl = classify_impl(art, use_pallas=use_pallas, tiles=tiles)
     if impl == "fused":
         return padded_rows(n, tiles.tile_n)
     if impl == "loop":
@@ -282,11 +284,9 @@ def fused_classify(art: TableArtifact, x, *, use_pallas=None,
     only) or the XLA gather reference ('ref') — all bit-identical, so the
     autotuner is free to pick whichever is fastest for the artifact shape.
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
     tiles = tiles or DEFAULT_TILES
     x = jnp.asarray(x, jnp.float32)
-    impl = tiles.impl if (use_pallas and fits_vmem(art)) else "ref"
+    impl = classify_impl(art, use_pallas=use_pallas, tiles=tiles)
 
     if art.ftable is not None:
         vote = art.agg == "vote"

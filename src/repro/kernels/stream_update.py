@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tuning import resolve_interpret
+from repro.kernels.tuning import EXACT_F32, resolve_interpret
 
 TILE_B = 512
 
@@ -80,7 +80,8 @@ def _stream_update_kernel(bucket_ref, ts_ref, len_ref, fwd_ref, valid_ref,
     contrib = jax.lax.dot_general(
         vals, matchv.astype(jnp.float32),
         dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (6, TILE_B)
+        preferred_element_type=jnp.float32,
+        precision=EXACT_F32)                                # (6, TILE_B)
 
     old = regs_ref[...]                                # (8, TILE_B)
     inf = jnp.float32(jnp.inf)

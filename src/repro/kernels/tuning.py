@@ -45,6 +45,15 @@ class TileConfig:
 
 DEFAULT_TILES = TileConfig()
 
+# Precision of every f32 matmul inside the kernels. Their operands are
+# integer payloads riding as f32 (one-hots against table codes, packet
+# lengths), and the results must be exact (DESIGN.md §2). The MXU's
+# default f32 precision is a single bf16 pass, which rounds any value
+# past 8 significant bits (a 1,001-byte packet, a decision key above
+# 256); HIGHEST splits each operand into three bf16 parts, which covers
+# the whole 24-bit f32 significand.
+EXACT_F32 = jax.lax.Precision.HIGHEST
+
 # Pallas-on-TPU sublane granularity for f32 (pallas_guide: min tile is
 # 8 x 128) — the floor any clamped batch tile must respect.
 MIN_TILE_N = 8
@@ -82,6 +91,17 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     return bool(interpret)
 
 
+def resolve_use_pallas(use_pallas: Optional[bool]) -> bool:
+    """None -> the Pallas kernels on TPU, the XLA references elsewhere.
+
+    The one platform rule every kernel wrapper and server applies, so a
+    server built without an explicit choice runs the kernels on the chip
+    and the exact references on CPU."""
+    if use_pallas is None:
+        return jax.default_backend() == "tpu"
+    return bool(use_pallas)
+
+
 def clear_tile_cache() -> None:
     _TILE_CACHE.clear()
 
@@ -116,26 +136,25 @@ def sweep_best(candidates, time_one, *, default, verbose: bool = False,
     ``candidates``) and the winner is the measured argmin over a set
     containing it — so by construction the sweep can never select a
     config that regresses versus the default on the tuned shape. A
-    candidate whose ``time_one`` raises is skipped (unsupported
-    config), mirroring the tile sweep; if every candidate fails the
-    default wins untimed.
+    non-default candidate whose ``time_one`` raises is skipped
+    (unsupported config). The default is timed first and its failure
+    propagates: it is what runs when nothing else wins, so a compile
+    error there (a kernel the chip's compiler refused) must surface,
+    not hide behind an untimed "winner".
     """
-    cands = list(candidates)
-    if default not in cands:
-        cands.append(default)
-    timings, best, best_dt = {}, default, float("inf")
-    for cand in cands:
+    timings = {}
+    for cand in [default] + [c for c in candidates if c != default]:
         try:
-            dt = time_one(cand)
+            timings[cand] = time_one(cand)
         except Exception:  # noqa: BLE001 — candidate probing: any raise
             #                (compile error, OOM, shape mismatch) just means
-            #                "config unsupported", and the default wins
+            #                "config unsupported" — except for the default
+            if cand == default:
+                raise
             continue
-        timings[cand] = dt
         if verbose:
-            print(f"{label} {cand} -> {dt * 1e3:.3f} ms")
-        if dt < best_dt:
-            best, best_dt = cand, dt
+            print(f"{label} {cand} -> {timings[cand] * 1e3:.3f} ms")
+    best = min(timings, key=timings.get)    # ties keep the default
     return best, timings
 
 

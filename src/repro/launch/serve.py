@@ -105,11 +105,13 @@ def main(argv=None):
         if args.backend == "ensemble":
             backend_fn.full_rows = jnp.asarray(xte[lo:lo + args.batch])
             # dispatch indices are produced inside classify; recompute here
-            # with the SAME switch realization the server uses
-            # (use_pallas=False default) so idx matches bit for bit —
-            # a different kernel path could order the dispatch differently
+            # with the SAME switch realization the server resolved
+            # (server.use_pallas) so idx matches bit for bit — a
+            # different kernel path could order the dispatch differently
             # and silently score the wrong full-feature rows
-            sw_pred, conf = fused_classify(art, rows, use_pallas=False)
+            sw_pred, conf = fused_classify(server.artifact, rows,
+                                           use_pallas=server.use_pallas,
+                                           tiles=server.tiles)
             from repro.core.hybrid import dispatch
             fwd = conf < args.threshold
             buf, idx, valid = dispatch(jnp.asarray(rows, jnp.float32), fwd,

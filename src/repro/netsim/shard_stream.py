@@ -52,7 +52,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed.sharding import flow_shard_mesh, flow_table_sharding
@@ -267,7 +266,7 @@ def _sharded_update_step(state: ShardedFlowTable, w: PacketWindow,
         return (jax.tree.map(lambda a: a[None], sq),
                 jnp.minimum(epoch, e))
 
-    regs, epoch = shard_map(
+    regs, epoch = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("shard", None), P("shard"), P()),
         out_specs=(P("shard", None), P("shard")))(

@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.ops import evict_fill, pad_window
+from repro.kernels.tuning import resolve_use_pallas
 from repro.netsim.features import (fnv1a_hash, rebase_ts_np,
                                    table_from_registers)
 
@@ -398,8 +399,7 @@ def window_update_readout(state: FlowTableState, w: PacketWindow, *,
         ``saturate_counts`` on an already-clamped file is a bitwise no-op
         that still counts newly saturated slots against ``prev``.
     """
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+    use_pallas = resolve_use_pallas(use_pallas)
     prev = state
     if not use_pallas:
         state = update_flow_table(state, w)
@@ -559,8 +559,7 @@ def chunk_update_readout(state: FlowTableState, chunk: PacketChunk, *,
     eviction is also on (an evicted slot could re-cross), where a
     carried below-envelope mask restores exact per-window counting.
     """
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+    use_pallas = resolve_use_pallas(use_pallas)
     # the packed fast path below inlines *timeout* eviction into the scan
     # body; the approx-LRU sweep (histogram + threshold per window) runs
     # through the generic per-window body instead — same shape as the

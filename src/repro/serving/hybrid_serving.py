@@ -36,7 +36,8 @@ import jax.numpy as jnp
 from repro.core.artifact import TableArtifact, finalize_artifact
 from repro.core.hybrid import combine, dispatch
 from repro.kernels.ops import fused_classify
-from repro.kernels.tuning import DEFAULT_TILES, TileConfig, autotune_tiles
+from repro.kernels.tuning import (DEFAULT_TILES, TileConfig, autotune_tiles,
+                                  resolve_use_pallas)
 
 
 class HybridStats:
@@ -85,14 +86,20 @@ class HybridServer:
 
     def __init__(self, artifact: TableArtifact, backend_fn: Callable,
                  *, threshold: float = 0.7, capacity: int = 256,
-                 use_pallas: bool = False, autotune: bool = False,
+                 use_pallas: Optional[bool] = None, autotune: bool = False,
                  donate: bool = False, tiles: Optional[TileConfig] = None,
                  fuse: Optional[bool] = None):
         """backend_fn: (rows (capacity, F)) -> class predictions (capacity,).
 
+        use_pallas: None (the default) resolves by platform — the Pallas
+        kernels on TPU, the bit-identical XLA references elsewhere
+        (``kernels.tuning.resolve_use_pallas``); the resolved bool is kept
+        as ``self.use_pallas``. Pass False for a reference server on a
+        TPU host, True to run the kernels in interpret mode on CPU.
+
         autotune=True sweeps kernel tile sizes once for this artifact shape
         (cached per shape+backend; only meaningful — and only run — when
-        use_pallas=True, since the XLA reference path ignores tile
+        the kernels are on, since the XLA reference path ignores tile
         configs). donate=True marks the input batch
         donatable to the fused step; with the current step outputs (pred
         (N,) i32 + scalar telemetry) nothing can alias an (N, F) f32 input,
@@ -113,7 +120,7 @@ class HybridServer:
         self._backend_fn = backend_fn
         self._capacity = capacity
         self.threshold = threshold
-        self.use_pallas = use_pallas
+        self.use_pallas = use_pallas = resolve_use_pallas(use_pallas)
         # tiles only steer the Pallas kernels; sweeping them for the XLA
         # reference path would be pure init latency
         self.tiles = tiles or (autotune_tiles(self.artifact)

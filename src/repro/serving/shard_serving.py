@@ -63,7 +63,6 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.artifact import TableArtifact
@@ -71,7 +70,8 @@ from repro.core.hybrid import (DeferredDispatch, backpatch_pending,
                                chunk_dispatch, dispatch, init_deferred)
 from repro.distributed.sharding import as_flow_mesh, flow_shard_mesh
 from repro.kernels.ops import classify_batch_rows, fused_classify
-from repro.kernels.tuning import TileConfig, shard_tiles
+from repro.kernels.tuning import (TileConfig, resolve_use_pallas,
+                                  shard_tiles)
 from repro.netsim.shard_stream import (ShardedFlowTable, gather_lane_values,
                                        init_sharded_table, lane_slab_rows,
                                        n_local_buckets, scatter_lane_slab,
@@ -136,9 +136,10 @@ class ShardedStreamingServer(StreamingHybridServer):
                  mesh: Optional[Mesh] = None, n_shards: Optional[int] = None,
                  n_data: Optional[int] = None,
                  partition_classify: bool = True,
-                 use_pallas: bool = False, autotune: bool = False,
+                 use_pallas: Optional[bool] = None, autotune: bool = False,
                  tiles: Optional[TileConfig] = None,
                  fuse: Optional[bool] = None, obs=None):
+        use_pallas = resolve_use_pallas(use_pallas)
         # mesh before super().__init__: the parent allocates the register
         # file through the _make_state hook, which needs it
         if mesh is not None:
@@ -229,23 +230,23 @@ class ShardedStreamingServer(StreamingHybridServer):
                     jnp.minimum(epoch, e),
                     sw_pred, fwd, buf, idx, valid, conf, counts)
 
-        # check_rep=False: jax's static replication checker cannot infer
+        # check_vma=False: jax's static replication checker cannot infer
         # replication through all_gather (the partitioned classify's
         # merge); the out_specs still pin the layout, and the bit-identity
         # oracles pin the values.
         state_specs = (P("shard", None), P("shard"), P(), P(), P())
-        shard_half = shard_map(
+        shard_half = jax.shard_map(
             functools.partial(_shard_body, merge_buf=True), mesh=self.mesh,
             in_specs=state_specs,
             out_specs=(P("shard", None), P("shard"),
                        P(), P(), P(), P(), P(), P(), P()),
-            check_rep=False)
-        defer_half = shard_map(
+            check_vma=False)
+        defer_half = jax.shard_map(
             functools.partial(_shard_body, merge_buf=False), mesh=self.mesh,
             in_specs=state_specs,
             out_specs=(P("shard", None), P("shard"),
                        P(), P(), P("shard", None, None), P(), P(), P(), P()),
-            check_rep=False)
+            check_vma=False)
 
         def _switch_half(art, state: ShardedFlowTable, w, threshold, *,
                          half=shard_half):
@@ -301,10 +302,10 @@ class ShardedStreamingServer(StreamingHybridServer):
             sl = jax.lax.dynamic_slice_in_dim(sl, i * per, per)
             return jnp.asarray(backend_fn(sl)).astype(jnp.int32)
 
-        flush_half = shard_map(_flush_body, mesh=self.mesh,
-                               in_specs=(P("shard", None, None),),
-                               out_specs=P(("shard", "data")),
-                               check_rep=False)
+        flush_half = jax.shard_map(_flush_body, mesh=self.mesh,
+                                   in_specs=(P("shard", None, None),),
+                                   out_specs=P(("shard", "data")),
+                                   check_vma=False)
 
         def flush_fused(stats, dd, pending):
             be_pred = flush_half(dd.buf)
@@ -369,12 +370,12 @@ class ShardedStreamingServer(StreamingHybridServer):
 
             dd_specs = DeferredDispatch(buf=P(), lane=P(), window=P(),
                                         valid=P())
-            chunk_part_half = shard_map(
+            chunk_part_half = jax.shard_map(
                 _chunk_part_body, mesh=self.mesh,
                 in_specs=(P("shard", None), P("shard"), P(), P(), P()),
                 out_specs=(P("shard", None), P("shard"),
                            P(), P(), P(), dd_specs, P(), P()),
-                check_rep=False)
+                check_vma=False)
 
             def chunk_switch(art, state, stats, chunk: PacketChunk,
                              threshold):
@@ -409,11 +410,11 @@ class ShardedStreamingServer(StreamingHybridServer):
                 return (jax.tree.map(lambda a: a[None], sq), ep[None],
                         xs, n_ev, n_ov)
 
-            chunk_scan_half = shard_map(
+            chunk_scan_half = jax.shard_map(
                 _chunk_scan_body, mesh=self.mesh,
                 in_specs=(P("shard", None), P("shard"), P()),
                 out_specs=(P("shard", None), P("shard"), P(), P(), P()),
-                check_rep=False)
+                check_vma=False)
 
             def chunk_switch(art, state, stats, chunk: PacketChunk,
                              threshold):
@@ -427,10 +428,10 @@ class ShardedStreamingServer(StreamingHybridServer):
 
         self._chunk_switch = jax.jit(chunk_switch, donate_argnums=(1, 2))
 
-        chunk_be_half = shard_map(
+        chunk_be_half = jax.shard_map(
             lambda bs: jnp.asarray(backend_fn(bs[0])).astype(jnp.int32),
             mesh=self.mesh, in_specs=(P(("shard", "data"), None, None),),
-            out_specs=P(("shard", "data")), check_rep=False)
+            out_specs=P(("shard", "data")), check_vma=False)
 
         def chunk_step(art, state, stats, chunk: PacketChunk, threshold):
             """Megastep with the mesh-wide backend: the chunk's deferred

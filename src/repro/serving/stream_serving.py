@@ -58,7 +58,8 @@ from repro.core.hybrid import (DeferredDispatch, backpatch_pending,
                                chunk_dispatch, combine, defer_window,
                                dispatch, init_deferred)
 from repro.kernels.ops import fused_classify
-from repro.kernels.tuning import (TileConfig, measure_min, sweep_best,
+from repro.kernels.tuning import (TileConfig, measure_min,
+                                  resolve_use_pallas, sweep_best,
                                   _artifact_key)
 from repro.netsim.ingest import (LatencyRecorder, PacketRingBuffer,
                                  cut_stream, prefetch_iter, replay_source)
@@ -551,7 +552,7 @@ class StreamingHybridServer(HybridServer):
                  evict_age: Optional[float] = None, saturate: bool = True,
                  evict_policy: str = "timeout", lru_occupancy: float = 0.75,
                  fault_policy: Optional[FaultPolicy] = None,
-                 use_pallas: bool = False, autotune: bool = False,
+                 use_pallas: Optional[bool] = None, autotune: bool = False,
                  tiles: Optional[TileConfig] = None,
                  fuse: Optional[bool] = None,
                  obs: Optional[Observability] = None):
@@ -645,6 +646,10 @@ class StreamingHybridServer(HybridServer):
         only ``sync_every > 0`` adds sampled blocking syncs, and only
         the per-``rollup_every`` boundary reads device stats.
         """
+        # resolved once (None -> kernels on TPU, references elsewhere) so
+        # the auto-K probes and every jitted closure below agree with
+        # ``self.use_pallas``
+        use_pallas = resolve_use_pallas(use_pallas)
         self._obs = obs
         if obs is not None:
             obs.bind(self)

@@ -3,8 +3,8 @@
 Two CI-trust contracts:
 
 * ``benchmarks/run.py --all-suites`` must exit nonzero the moment a
-  sub-suite subprocess fails (propagating the child's code), so an
-  oracle failure in any emitter can never leave CI green;
+  sub-suite fails (propagating its exit code), so an oracle failure in
+  any emitter can never leave CI green;
 * every ``BENCH_*.json`` must satisfy the bench-v1 schema before it is
   uploaded into the perf trajectory — ``benchmarks.validate_schema``
   is the gate and must reject malformed payloads.
@@ -12,6 +12,9 @@ Two CI-trust contracts:
 
 import copy
 import json
+import sys
+import types
+from pathlib import Path
 
 import pytest
 
@@ -27,8 +30,8 @@ from benchmarks.validate_schema import (SchemaError, main as validate_main,
 # ---------------------------------------------------------------------------
 
 def test_run_suites_propagates_child_failure():
-    """A failing suite subprocess must abort the run with a nonzero exit
-    code — the child's own — not be swallowed into a summary."""
+    """A failing suite must abort the run with a nonzero exit code, not
+    be swallowed into a summary."""
     with pytest.raises(SystemExit) as e:
         run_suites(("definitely_not_a_bench_module",))
     assert e.value.code not in (0, None)
@@ -46,6 +49,55 @@ def test_run_suites_failure_is_fail_fast(capfd):
 
 def test_run_suites_empty_returns_cleanly():
     assert run_suites(()) is None
+
+
+@pytest.fixture()
+def fake_suite(monkeypatch):
+    """A stand-in ``benchmarks._fake_suite`` whose main runs ``body``."""
+    mod = types.ModuleType("benchmarks._fake_suite")
+    monkeypatch.setitem(sys.modules, "benchmarks._fake_suite", mod)
+    return mod
+
+
+@pytest.mark.parametrize("exc, code", [(SystemExit(3), 3),
+                                       (RuntimeError("oracle"), 1)])
+def test_run_suites_in_process_exit_codes(fake_suite, exc, code):
+    """Suites run in this process through main(argv): a nonzero
+    SystemExit keeps its code, an exception exits 1."""
+    def main(argv):
+        raise exc
+    fake_suite.main = main
+    with pytest.raises(SystemExit) as e:
+        run_suites(("_fake_suite",))
+    assert e.value.code == code
+
+
+def test_run_suites_passes_quick_to_main(fake_suite):
+    seen = []
+    fake_suite.main = seen.append
+    run_suites(("_fake_suite",), quick=True)
+    run_suites(("_fake_suite",))
+    assert seen == [["--quick"], []]
+
+
+def test_compile_cache_env_wins_else_fixed_checkout_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache and nothing else
+    is named; otherwise the cache sits at <checkout>/.jax_cache."""
+    import jax
+    from repro.launch import compile_cache as cc
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", "untouched")
+        monkeypatch.setenv(cc.ENV_VAR, "/from/env")
+        assert cc.configure_compile_cache() == "/from/env"
+        assert jax.config.jax_compilation_cache_dir == "untouched"
+        monkeypatch.delenv(cc.ENV_VAR)
+        assert cc.configure_compile_cache() == str(cc.CHECKOUT_CACHE)
+        assert jax.config.jax_compilation_cache_dir == str(cc.CHECKOUT_CACHE)
+        assert cc.CHECKOUT_CACHE == (Path(__file__).resolve().parents[1]
+                                     / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
 
 
 def test_all_suites_list_covers_every_emitter():

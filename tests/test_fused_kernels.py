@@ -180,3 +180,38 @@ def test_tile_config_override_bit_exact(anomaly_data):
         np.testing.assert_array_equal(np.asarray(p), np.asarray(p_ref))
         np.testing.assert_allclose(np.asarray(c), np.asarray(c_ref),
                                    atol=1e-6)
+
+
+def test_classify_impl_routes_by_platform_and_fit(anomaly_data, monkeypatch):
+    """classify_impl is the one routing decision fused_classify and
+    classify_batch_rows share: None resolves by platform (CPU here: the
+    reference), explicit kernels run tiles.impl, and an artifact past the
+    VMEM budget takes the reference even with the kernels on."""
+    from repro.kernels import ops
+    xtr, ytr, _, _ = anomaly_data
+    art = finalize_artifact(_fit_artifact("RF", xtr, ytr))
+    assert ops.classify_impl(art) == "ref"
+    assert ops.classify_impl(art, use_pallas=True) == "fused"
+    assert ops.classify_impl(art, use_pallas=True,
+                             tiles=TileConfig(impl="loop")) == "loop"
+    assert ops.classify_batch_rows(art, 300, use_pallas=True) == 384
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.classify_impl(art) == "fused"
+    monkeypatch.setattr(ops, "VMEM_BUDGET_BYTES", 0)
+    assert ops.classify_impl(art) == "ref"
+    assert ops.classify_batch_rows(art, 300) == 300
+
+
+def test_sweep_best_raises_when_the_default_fails():
+    """A failing non-default candidate is skipped; a failing default
+    propagates (it is what would run), never an untimed 'winner'."""
+    from repro.kernels.tuning import sweep_best
+
+    def timer(c):
+        if c == "broken":
+            raise RuntimeError("refused by the compiler")
+        return {"a": 2.0, "b": 1.0}[c]
+    best, timings = sweep_best(["a", "broken", "b"], timer, default="a")
+    assert best == "b" and set(timings) == {"a", "b"}
+    with pytest.raises(RuntimeError, match="refused"):
+        sweep_best(["a", "b"], timer, default="broken")

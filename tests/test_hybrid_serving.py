@@ -150,3 +150,22 @@ def test_generate_matches_rerun_prefill():
     logits, _ = M.prefill(params, cfg, {"tokens": full})
     expect = jnp.argmax(logits, axis=-1)
     np.testing.assert_array_equal(np.asarray(out[:, 2]), np.asarray(expect))
+
+
+def test_servers_resolve_use_pallas_by_platform(hybrid_setup, monkeypatch):
+    """use_pallas=None (the default on every server) resolves by platform:
+    the XLA references on CPU, the Pallas kernels on a TPU; an explicit
+    value is kept."""
+    from repro.serving.shard_serving import ShardedStreamingServer
+    from repro.serving.stream_serving import StreamingHybridServer
+    art, _, big, _, _ = hybrid_setup
+    be = lambda x: predict_tree_ensemble(big, x)
+    kw = dict(n_buckets=1024, window=128)
+    assert HybridServer(art, be).use_pallas is False
+    assert StreamingHybridServer(art, be, **kw).use_pallas is False
+    assert ShardedStreamingServer(art, be, n_shards=1, **kw).use_pallas \
+        is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert HybridServer(art, be).use_pallas is True
+    assert HybridServer(art, be, use_pallas=False).use_pallas is False
+    assert StreamingHybridServer(art, be, **kw).use_pallas is True

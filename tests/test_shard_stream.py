@@ -561,6 +561,24 @@ def test_legacy_1d_mesh_normalizes(shard_setup):
     _assert_matches_single_device(trace, art, backend, srv, **SERVE_KW)
 
 
+@pytest.mark.parametrize("make", ["flow_shard_mesh", "explicit_2d",
+                                  "legacy_1d"])
+def test_flow_meshes_have_auto_axes(make):
+    """The streaming tier is written for Auto axes. jax.make_mesh makes
+    Explicit ones by default, under which the deferral buffer's
+    dynamic_update_slice is a sharding type error; every flow-table mesh
+    (built here or normalized from a caller's) comes out Auto."""
+    from jax.sharding import AxisType, Mesh
+    from repro.distributed.sharding import as_flow_mesh, flow_shard_mesh
+    devs = np.array(jax.devices()[:1])
+    mesh = {"flow_shard_mesh": lambda: flow_shard_mesh(),
+            "explicit_2d": lambda: as_flow_mesh(jax.make_mesh(
+                (1, 1), ("shard", "data"))),
+            "legacy_1d": lambda: as_flow_mesh(Mesh(devs, ("shard",)))}[make]()
+    assert mesh.axis_names == ("shard", "data")
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+
+
 def test_collision_storm_uneven_ownership_never_drops_rows(shard_setup):
     """Uneven-ownership stress: a collision_storm trace concentrates
     nearly all touched buckets on whichever shards own the few target
