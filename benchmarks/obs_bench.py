@@ -5,7 +5,7 @@
 
 * **bit-identity oracle** — a server built with an ``Observability``
   (events to a JSON-lines sink, metric rollups every ``rollup_every``
-  chunks, drift monitors on, the default ``sync_every=0``) must return
+  chunks, drift monitors on, stage spans on the profiler's clock) must return
   predictions bit-identical to an obs-free server on the same replay,
   on BOTH the chunked and the per-window serving paths. Telemetry that
   changes the answer is a bug, not a feature.
@@ -161,8 +161,7 @@ def run(n_flows=3000, window=256, chunk_windows=8, n_buckets=1 << 13,
             obs_floor=obs_floor, events_path=events_path)
         rows += path_rows
         ratios[label] = ratio
-    print_table(f"Observability overhead (rollup_every={rollup_every}, "
-                f"sync_every=0)",
+    print_table(f"Observability overhead (rollup_every={rollup_every})",
                 ["config", "pkts/s", "ratio", "events", "rollups"],
                 [[r["config"], r["pkts_per_s"], r["throughput_ratio"],
                   r["events"], r["rollups"]] for r in rows])

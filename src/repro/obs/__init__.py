@@ -12,8 +12,9 @@ hooks the serving tiers call:
                               behind one ``snapshot()`` (obs/metrics.py);
   rollups   ``obs.rollups`` — keyed per-N-dispatches windowed aggregation
                               (obs/metrics.RollupWindows);
-  timing    ``obs.timer``   — per-stage wall timers with sampled device
-                              synchronization (obs/profiling.py);
+  timing    ``obs.timer``   — per-stage wall timers, each stage also a
+                              ``span`` on the profiler's clock
+                              (obs/profiling.py);
   drift     ``obs.drift``   — confidence-collapse / fraction_handled /
                               class-mix monitors over the rollup rows
                               (obs/drift.py), emitting ``drift_alarm``
@@ -25,9 +26,15 @@ guarded by ``if obs is not None`` — and is bit-identical to pre-obs
 serving. A server built with an ``Observability`` emits host-side events
 and, once per ``rollup_every`` dispatches (a dispatch = one chunk
 megastep or one window step), reads its device stats ONCE to close a
-rollup window; at the default ``sync_every=0`` it never adds a blocking
-device sync, so predictions stay bit-identical and throughput within the
-BENCH_obs.json gate (≥0.9x).
+rollup window; it never adds another blocking device sync, so
+predictions stay bit-identical and throughput within the BENCH_obs.json
+gate (≥0.9x).
+
+``span`` and ``op_scopes`` serve every tier with or without an
+``Observability``: ``span(name, **ids)`` is the program's one host span
+primitive (a ``jax.profiler.TraceAnnotation``, a no-op outside a
+profiler capture), and ``op_scopes`` maps the instructions of a compiled
+step to the ``jax.named_scope`` they ran under.
 
 Usage::
 
@@ -40,6 +47,7 @@ Usage::
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Optional
 
@@ -51,8 +59,7 @@ from repro.obs.events import (EVENT_KINDS, EVENT_SCHEMA_VERSION, Event,
                               validate_event_log)
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                RollupWindows)
-from repro.obs.profiling import (STAGES, SampledSync, StageTimer,
-                                 annotation)
+from repro.obs.profiling import STAGES, StageTimer, op_scopes, span
 
 __all__ = [
     "DETECTORS", "DriftAlarm", "DriftConfig", "DriftMonitor",
@@ -60,7 +67,7 @@ __all__ = [
     "EventSchemaError", "JsonlSink", "iter_event_lines",
     "validate_event_line", "validate_event_log",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "RollupWindows",
-    "STAGES", "SampledSync", "StageTimer", "annotation",
+    "STAGES", "StageTimer", "op_scopes", "span",
     "ObsConfig", "Observability",
 ]
 
@@ -74,20 +81,12 @@ class ObsConfig:
     rollup_every   dispatches (chunk megasteps / window steps) per rollup
                    window — also the cadence of the ONE device-stats read
                    the serving loop takes per window;
-    sync_every     sampled-synchronization cadence: every N-th dispatch
-                   blocks until device-complete inside the
-                   ``megastep_synced`` stage (0 = never, the default —
-                   the zero-sync loop is preserved exactly);
-    annotate       wrap megasteps in ``jax.profiler.TraceAnnotation``
-                   (visible in captured profiler traces only);
     drift          DriftConfig of the monitors (None: defaults);
     drift_enabled  False disables drift detection entirely.
     """
     events_path: Optional[str] = None
     max_events: int = 65536
     rollup_every: int = 8
-    sync_every: int = 0
-    annotate: bool = False
     drift: Optional[DriftConfig] = None
     drift_enabled: bool = True
 
@@ -117,7 +116,6 @@ class Observability:
         # dispatches each, so every observed sample closes one row
         self.rollups = RollupWindows(every=1)
         self.timer = StageTimer()
-        self.sync = SampledSync(c.sync_every)
         self.drift = DriftMonitor(c.drift) if c.drift_enabled else None
         self._ticks = 0           # dispatches since the last rollup row
 
@@ -150,19 +148,12 @@ class Observability:
     def emit(self, kind: str, **fields) -> Event:
         return self.events.emit(kind, **fields)
 
+    @contextlib.contextmanager
     def stage(self, name: str):
-        """Time a pipeline stage (context manager)."""
-        return self.timer.stage(name)
-
-    def annotate(self, name: str):
-        """Profiler trace annotation around a megastep (null context
-        unless ``annotate`` is configured)."""
-        return annotation(name, self.config.annotate)
-
-    def sync_due(self) -> bool:
-        """Sampled synchronization: should this dispatch block until
-        device-complete (inside the ``megastep_synced`` stage)?"""
-        return self.sync.due()
+        """Time a pipeline stage on the host clock, inside a ``span`` of
+        the same name on the profiler's clock (context manager)."""
+        with span(name), self.timer.stage(name):
+            yield
 
     def tick(self) -> bool:
         """Count one dispatch; True at each rollup boundary."""

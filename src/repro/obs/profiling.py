@@ -1,34 +1,34 @@
-"""Per-stage timing for the serving pipeline, with sampled device sync.
+"""Spans on the profiler's clock, per-stage host timing, and the scopes of
+a compiled step.
 
-JAX serving is asynchronous: ``step_chunk`` *enqueues* a megastep and
-returns, so naive host timers around it measure dispatch latency, not
-device work. The honest decomposition this module provides:
+* ``span(name, **ids)`` — the one span primitive of the program: a
+  ``jax.profiler.TraceAnnotation`` named ``name`` whose keyword ids
+  (``call=7``) become stats of the event. Outside a profiler capture it
+  costs a TraceMe no-op; inside one it lands on the host thread's line
+  of the same trace as the device's ops, so a device idle gap can be
+  laid against what the host was doing. The two clocks do not always
+  agree: on a TPU v5e a process can record every device event about a
+  millisecond early against the host's, so check the order of a step
+  and the span that dispatched it before such a split.
 
 * ``StageTimer.stage(name)`` — wall-time a pipeline stage (ring cut,
   host pack, H2D transfer, megastep dispatch, backend flush,
   back-patch). Durations accumulate per stage with a bounded sample
   ring for percentiles; thread-safe enough for the prefetch thread
-  (list/deque appends are atomic under the GIL).
+  (list/deque appends are atomic under the GIL). JAX dispatch is
+  asynchronous, so a stage around a dispatch times the enqueue; device
+  time comes from a profiler trace. ``Observability.stage`` opens a
+  ``span`` of the same name around the timing.
 
-* **sampled synchronization** — every ``sync_every``-th chunk (the
-  knob; 0 = never, the default) the serving loop blocks until that
-  chunk's predictions are device-complete inside a ``*_synced`` stage,
-  so the sampled duration covers enqueue + device execution. Sampling
-  bounds the pipelining cost: a sync drains the dispatch queue, which
-  is exactly why it is off by default and why N trades fidelity against
-  throughput. Sync changes *when* the host waits, never a value — the
-  bit-identity oracle covers it.
-
-* ``annotation(name)`` — ``jax.profiler.TraceAnnotation`` context for
-  the megastep when a profiler trace is being captured (shows the
-  serving loop's phases in TensorBoard/Perfetto); a null context when
-  disabled so the default path stays allocation-free.
+* ``op_scopes(hlo_text)`` — which ``jax.named_scope`` each instruction of
+  an optimized HLO module ran under. TPU op events in a trace carry the
+  instruction's name (``%fusion.9 = ...``) but no scope, so this map is
+  how a trace's device time is split by the step's scopes.
 
 Stage vocabulary used by the serving tiers (DESIGN.md §14): ``ring_cut``
 (pull source + admit + window-granular pack), ``h2d`` (HostCut ->
 device PacketChunk transfer; queue wait when the prefetch thread owns
-the transfer), ``megastep`` (step dispatch), ``megastep_synced``
-(sampled: dispatch + device completion), ``backend_flush`` (host
+the transfer), ``megastep`` (step dispatch), ``backend_flush`` (host
 backend call on the two-phase path), ``backpatch`` (jitted back-patch
 dispatch). The register scan and fused classify live *inside* the
 megastep's single dispatch — they are separated with ``jax.named_scope``
@@ -40,13 +40,21 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import re
 import time
-from typing import Callable, Optional
+from typing import Callable
 
+import jax
 import numpy as np
 
-STAGES = ("ring_cut", "h2d", "megastep", "megastep_synced",
-          "backend_flush", "backpatch")
+STAGES = ("ring_cut", "h2d", "megastep", "backend_flush", "backpatch")
+
+
+def span(name: str, **ids):
+    """A host span ``name`` on the profiler's clock, with ``ids`` (ints or
+    short strings, e.g. ``call=7``) as stats of the event; see the
+    module doc."""
+    return jax.profiler.TraceAnnotation(name, **ids)
 
 
 class StageTimer:
@@ -106,41 +114,62 @@ class StageTimer:
         self._acc.clear()
 
 
-class SampledSync:
-    """Every-N counter deciding which chunks get a blocking device sync.
-
-    ``due()`` advances the counter and returns True on the N-th, 2N-th,
-    ... call; ``every=0`` (default) never syncs — the zero-sync serving
-    loop is preserved exactly.
-    """
-
-    def __init__(self, every: int = 0):
-        if every < 0:
-            raise ValueError(f"sync_every must be >= 0, got {every}")
-        self.every = every
-        self._i = 0
-
-    def due(self) -> bool:
-        if not self.every:
-            return False
-        self._i += 1
-        if self._i >= self.every:
-            self._i = 0
-            return True
-        return False
 
 
-def annotation(name: str, enabled: bool = True):
-    """``jax.profiler.TraceAnnotation`` context when enabled (and the
-    profiler is importable), else a null context. Annotations are only
-    visible inside a captured profiler trace; outside one they cost a
-    TraceMe no-op."""
-    if not enabled:
-        return contextlib.nullcontext()
-    try:
-        import jax.profiler
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — telemetry never raises; any
-        #                profiler import/init failure degrades to a null
-        #                context  # pragma: no cover - profiler unavailable
-        return contextlib.nullcontext()
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(r"\b(?:calls|to_apply)=%([\w.\-]+)")
+
+
+def _scope(op_name: str):
+    """The scope of ``jit(<fn>)/<scope>/.../<op>``: the element after the
+    outer ``jit(...)``, when another element follows it (a bare
+    ``jit(<fn>)/<op>`` ran under no scope)."""
+    parts = op_name.split("/")
+    return parts[1] if len(parts) > 2 else None
+
+
+def op_scopes(hlo_text: str) -> dict:
+    """{instruction name: scope} of an optimized HLO module's text
+    (``jax.jit(f).lower(...).compile().as_text()``).
+
+    The name keeps its ``.N`` (``fusion.9``), as a TPU op event's text
+    starts (``%fusion.9 = ...``). The scope is the first element of the
+    instruction's ``op_name`` after the outer ``jit(<fn>)/``. An
+    instruction whose own metadata names no scope, such as a fusion XLA
+    made, takes the scope that most instructions of the computations it
+    calls (``calls=``, ``to_apply=``) have, found the same way.
+    Instructions with neither are left out."""
+    own, callees, comps = {}, {}, collections.defaultdict(list)
+    comp = None
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if m is None:
+            if line.endswith("{") and not line.startswith(" "):
+                head = line.split()
+                comp = head[1 if head[0] == "ENTRY" else 0].lstrip("%")
+            continue
+        name = m.group(1)
+        comps[comp].append(name)
+        op = _OP_NAME.search(line)
+        scope = _scope(op.group(1)) if op else None
+        if scope is not None:
+            own[name] = scope
+        else:
+            callees[name] = _CALLED.findall(line)
+
+    memo: dict = {}
+
+    def resolve(name):
+        if name in own:
+            return own[name]
+        if name not in memo:
+            memo[name] = None              # HLO calls form no cycle
+            votes = collections.Counter(
+                s for c in callees.get(name, ()) for i in comps.get(c, ())
+                if (s := resolve(i)) is not None)
+            memo[name] = votes.most_common(1)[0][0] if votes else None
+        return memo[name]
+
+    return {n: s for names in comps.values() for n in names
+            if (s := resolve(n)) is not None}

@@ -11,7 +11,9 @@ Request path:
      that buffer hits the BACKEND — either the full-grown ensemble
      (paper-faithful) or an LM scorer. This is the paper's back-end load
      reduction, in batch-size form: the expensive model runs on
-     capacity-many rows, not on the full batch.
+     capacity-many rows, not on the full batch. ``HybridStats.backend_rows``
+     / ``capacity`` is the share of that work that is used: the fill of
+     the dispatch buffer.
 
 Zero-sync single-dispatch path: switch classify + dispatch + backend +
 combine are ONE jitted, buffer-donating function, so a classify() is a
@@ -24,6 +26,17 @@ Backends that cannot be traced (e.g. they call into a foreign runtime)
 are detected on the first classify and served by a two-phase fallback:
 jitted switch+dispatch, host backend call, jitted combine — still one
 host hop fewer than the pre-refactor path.
+
+Tracing: every classify opens host spans (``repro.obs.span``) that share
+the call's id, ``hybrid.h2d`` around the conversion of the rows and tau
+to device arrays and ``hybrid.dispatch`` around the step (on the
+two-phase path around the whole call, with ``hybrid.backend_host``
+inside it around the host backend). Inside the step, ``jax.named_scope``
+marks ``switch`` (fused classify), ``dispatch`` (threshold, sort,
+gather), ``backend`` and ``combine``; TPU op events carry no scope, so
+``HybridServer.step_scopes`` maps the compiled step's instructions to
+them. The host counter ``calls`` numbers the requests and gives the
+spans their id; it adds no device sync.
 """
 
 from __future__ import annotations
@@ -38,6 +51,7 @@ from repro.core.hybrid import combine, dispatch
 from repro.kernels.ops import fused_classify
 from repro.kernels.tuning import (DEFAULT_TILES, TileConfig, autotune_tiles,
                                   resolve_use_pallas)
+from repro.obs import op_scopes, span
 
 
 class HybridStats:
@@ -46,6 +60,10 @@ class HybridStats:
     Reading .fraction_handled / .backend_rows is the only point that
     blocks on the device — constructing or returning HybridStats never
     does, which keeps classify() fully asynchronous.
+
+    ``backend_rows`` is the dispatch layer's counter: the rows of the
+    call that reached the backend, at most ``capacity``, the buffer the
+    backend evaluates whole.
     """
 
     __slots__ = ("_fraction_handled", "_backend_rows", "capacity")
@@ -126,29 +144,29 @@ class HybridServer:
         self.tiles = tiles or (autotune_tiles(self.artifact)
                                if autotune and use_pallas else DEFAULT_TILES)
         self._fused_ok = fuse                   # None = not yet probed
+        self.calls = 0                          # classify calls; span ids
+
+        def switch_only(art, x, threshold):
+            with jax.named_scope("switch"):
+                sw_pred, conf = fused_classify(art, x, use_pallas=use_pallas,
+                                               tiles=self.tiles)
+            with jax.named_scope("dispatch"):
+                fwd = conf < threshold
+                buf, idx, valid = dispatch(x, fwd, capacity)
+                frac = 1.0 - jnp.mean(fwd.astype(jnp.float32))
+                rows = jnp.sum(valid.astype(jnp.int32))
+            return sw_pred, buf, idx, valid, frac, rows
 
         def step(art, x, threshold):
-            sw_pred, conf = fused_classify(art, x, use_pallas=use_pallas,
-                                           tiles=self.tiles)
-            fwd = conf < threshold
-            buf, idx, valid = dispatch(x, fwd, capacity)
-            be_pred = jnp.asarray(backend_fn(buf))
-            pred = combine(sw_pred, be_pred, idx, valid)
-            frac = 1.0 - jnp.mean(fwd.astype(jnp.float32))
-            rows = jnp.sum(valid.astype(jnp.int32))
+            sw_pred, buf, idx, valid, frac, rows = switch_only(art, x,
+                                                               threshold)
+            with jax.named_scope("backend"):
+                be_pred = jnp.asarray(backend_fn(buf))
+            with jax.named_scope("combine"):
+                pred = combine(sw_pred, be_pred, idx, valid)
             return pred, frac, rows
 
         self._step = jax.jit(step, donate_argnums=(1,) if donate else ())
-
-        def switch_only(art, x, threshold):
-            sw_pred, conf = fused_classify(art, x, use_pallas=use_pallas,
-                                           tiles=self.tiles)
-            fwd = conf < threshold
-            buf, idx, valid = dispatch(x, fwd, capacity)
-            frac = 1.0 - jnp.mean(fwd.astype(jnp.float32))
-            rows = jnp.sum(valid.astype(jnp.int32))
-            return sw_pred, buf, idx, valid, frac, rows
-
         self._switch_only = jax.jit(switch_only)
         self._combine = jax.jit(combine)
 
@@ -166,27 +184,44 @@ class HybridServer:
     def classify(self, x):
         """x (N, F) -> (pred (N,), HybridStats). Fully async: nothing here
         blocks on the device; read the stats (or the preds) to sync."""
-        x = jnp.asarray(x, jnp.float32)
-        tau = jnp.float32(self.threshold)
-        if self._fused_ok is None:
-            try:
+        call = self.calls
+        self.calls += 1
+        with span("hybrid.h2d", call=call):
+            x = jnp.asarray(x, jnp.float32)
+            tau = jnp.float32(self.threshold)
+        with span("hybrid.dispatch", call=call):
+            if self._fused_ok is None:
+                try:
+                    pred, frac, rows = self._step(self.artifact, x, tau)
+                    self._fused_ok = True
+                    return pred, HybridStats(frac, rows, self.capacity)
+                except (jax.errors.JAXTypeError, TypeError):
+                    # backend_fn is not traceable; tracing failed before
+                    # any execution, so x was not consumed by the donation
+                    self._fused_ok = False
+            if self._fused_ok:
                 pred, frac, rows = self._step(self.artifact, x, tau)
-                self._fused_ok = True
                 return pred, HybridStats(frac, rows, self.capacity)
-            except (jax.errors.JAXTypeError, TypeError):
-                # backend_fn is not traceable; tracing failed before any
-                # execution, so x was not consumed by the donation
-                self._fused_ok = False
-        if self._fused_ok:
-            pred, frac, rows = self._step(self.artifact, x, tau)
-            return pred, HybridStats(frac, rows, self.capacity)
-        # two-phase fallback: untraceable backend runs on host between
-        # the jitted switch half and the jitted combine
-        sw_pred, buf, idx, valid, frac, rows = self._switch_only(
-            self.artifact, x, tau)
-        be_pred = jnp.asarray(self.backend_fn(buf))
-        pred = self._combine(sw_pred, be_pred, idx, valid)
+            # two-phase fallback: untraceable backend runs on host between
+            # the jitted switch half and the jitted combine
+            sw_pred, buf, idx, valid, frac, rows = self._switch_only(
+                self.artifact, x, tau)
+            with span("hybrid.backend_host", call=call):
+                be_pred = jnp.asarray(self.backend_fn(buf))
+            pred = self._combine(sw_pred, be_pred, idx, valid)
         return pred, HybridStats(frac, rows, self.capacity)
+
+    def step_scopes(self, n_rows: int) -> dict:
+        """{instruction name: scope} of the fused step compiled for
+        ``n_rows`` rows (``repro.obs.op_scopes``): how a profiler trace's
+        op events of ``jit_step`` split into ``switch``, ``dispatch``,
+        ``backend`` and ``combine``. Compiles (or loads) the step; call it
+        outside a timed window."""
+        x = jax.ShapeDtypeStruct((n_rows, self.artifact.n_features),
+                                 jnp.float32)
+        compiled = self._step.lower(self.artifact, x,
+                                    jnp.float32(self.threshold)).compile()
+        return op_scopes(compiled.as_text())
 
     def update_tables(self, artifact: TableArtifact):
         """§4.4: retraining swaps table *contents*; nothing recompiles as

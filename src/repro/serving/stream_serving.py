@@ -643,8 +643,7 @@ class StreamingHybridServer(HybridServer):
         no observability branch anywhere and is bit-identical to pre-obs
         serving; with an instance attached, all hooks stay host-side and
         predictions remain bit-identical (the BENCH_obs.json oracle) —
-        only ``sync_every > 0`` adds sampled blocking syncs, and only
-        the per-``rollup_every`` boundary reads device stats.
+        only the per-``rollup_every`` boundary reads device stats.
         """
         # resolved once (None -> kernels on TPU, references elsewhere) so
         # the auto-K probes and every jitted closure below agree with
@@ -1284,9 +1283,8 @@ class StreamingHybridServer(HybridServer):
         loop emits lifecycle events (serve_begin/cut/chunk/window/
         flush/rollup/serve_end), times pipeline stages, closes a metric
         rollup window every ``rollup_every`` dispatches (the loop's only
-        device-stats read), feeds the drift monitors, and — only when
-        ``sync_every > 0`` — samples a blocking device sync as the
-        ``megastep_synced`` stage. Predictions, flow table, and
+        device-stats read), and feeds the drift monitors; each stage is
+        also a span on the profiler's clock. Predictions, flow table, and
         StreamStats stay bit-identical with obs attached (oracle-gated
         in tests and benchmarks/obs_bench.py).
 
@@ -1371,7 +1369,7 @@ class StreamingHybridServer(HybridServer):
                 if obs is not None:
                     obs.emit("cut", cut_kind=cut.kind, packets=cut.n,
                              windows=cut.n_windows)
-                    with obs.annotate("megastep"), obs.stage("megastep"):
+                    with obs.stage("megastep"):
                         pred, _ = self.step_chunk(chunk)
                 else:
                     pred, _ = self.step_chunk(chunk)
@@ -1382,9 +1380,6 @@ class StreamingHybridServer(HybridServer):
                 preds.append(flat)
                 if obs is not None:
                     obs.emit("chunk", windows=cut.n_windows, packets=cut.n)
-                    if obs.sync_due():
-                        with obs.stage("megastep_synced"):
-                            jax.block_until_ready(flat)
                     if obs.tick():
                         obs_prev, obs_b0 = self._obs_rollup(
                             obs, preds, obs_b0, obs_prev,
@@ -1420,7 +1415,7 @@ class StreamingHybridServer(HybridServer):
                          windows=cut.n_windows)
             for w in cut.to_windows():
                 if obs is not None:
-                    with obs.annotate("window_step"), obs.stage("megastep"):
+                    with obs.stage("megastep"):
                         pred, _ = self.step(w)
                 else:
                     pred, _ = self.step(w)
@@ -1434,9 +1429,6 @@ class StreamingHybridServer(HybridServer):
                     _patch(fl)
                 if obs is not None:
                     obs.emit("window", packets=cut.n)
-                    if obs.sync_due():
-                        with obs.stage("megastep_synced"):
-                            jax.block_until_ready(pred)
                     if obs.tick():
                         # never collapse: _patch slices preds per window
                         obs_prev, obs_b0 = self._obs_rollup(

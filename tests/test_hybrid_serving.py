@@ -169,3 +169,138 @@ def test_servers_resolve_use_pallas_by_platform(hybrid_setup, monkeypatch):
     assert HybridServer(art, be).use_pallas is True
     assert HybridServer(art, be, use_pallas=False).use_pallas is False
     assert StreamingHybridServer(art, be, **kw).use_pallas is True
+
+
+# ---------------------------------------------------------------------------
+# spans, scopes and counters of classify
+# ---------------------------------------------------------------------------
+
+def _backend(big, path):
+    """A traceable backend (the fused step), or one that converts its rows
+    to numpy and so runs on the host (the two-phase path)."""
+    if path == "fused":
+        return lambda r: predict_tree_ensemble(big, r)
+    return lambda r: predict_tree_ensemble(big, np.asarray(r))
+
+
+@pytest.mark.parametrize("path", ["fused", "two_phase"])
+def test_classify_opens_its_spans_in_order(hybrid_setup, monkeypatch, path):
+    """Each call opens hybrid.h2d, then hybrid.dispatch (with
+    hybrid.backend_host inside it on the two-phase path), all with the
+    call's id."""
+    import contextlib
+
+    import repro.serving.hybrid_serving as hs
+    art, small, big, xte, yte = hybrid_setup
+    events = []
+
+    @contextlib.contextmanager
+    def span(name, **ids):
+        events.append(("open", name, ids))
+        yield
+        events.append(("close", name, ids))
+
+    monkeypatch.setattr(hs, "span", span)
+    srv = HybridServer(art, _backend(big, path), threshold=0.7,
+                       capacity=128)
+    for _ in range(2):
+        srv.classify(xte[:256])
+    inner = ["hybrid.backend_host"] if path == "two_phase" else []
+    want = []
+    for call in range(2):
+        ids = {"call": call}
+        want += [("open", "hybrid.h2d", ids), ("close", "hybrid.h2d", ids),
+                 ("open", "hybrid.dispatch", ids)]
+        want += [(kind, n, ids) for n in inner for kind in ("open", "close")]
+        want += [("close", "hybrid.dispatch", ids)]
+    assert events == want
+    assert srv._fused_ok is (path == "fused")
+
+
+@pytest.mark.parametrize("path", ["fused", "two_phase"])
+def test_classify_counts_calls(hybrid_setup, path):
+    """``calls`` counts requests on either path, and the ids it hands the
+    spans run 0, 1, 2, ..."""
+    art, small, big, xte, yte = hybrid_setup
+    srv = HybridServer(art, _backend(big, path), threshold=0.7,
+                       capacity=128)
+    assert srv.calls == 0
+    for n in (256, 100, 256):
+        srv.classify(xte[:n])
+    assert srv.calls == 3
+    assert srv._fused_ok is (path == "fused")
+
+
+HLO = """HloModule jit_step, entry_computation_layout={(f32[8]{0})->f32[4]{0}}
+
+%region_0.1 (a: s32[], b: s32[]) -> s32[] {
+  %a = s32[] parameter(0), metadata={op_name="reduce_sum"}
+  %b = s32[] parameter(1)
+  ROOT %add.2 = s32[] add(%a, %b), metadata={op_name="jit(step)/switch/reduce_sum" stack_frame_id=20}
+}
+
+%wrapped_computation (p: s32[8]) -> s32[] {
+  %p = s32[8]{0} parameter(0)
+  %zero = s32[] constant(0)
+  ROOT %reduce.3 = s32[] reduce(%p, %zero), dimensions={0}, to_apply=%region_0.1
+}
+
+%fused_computation.9 (p0: f32[8], p1: s32[4]) -> f32[4] {
+  %p0 = f32[8]{0} parameter(0)
+  %p1 = s32[4]{0} parameter(1)
+  %g.1 = f32[4]{0} gather(%p0, %p1), metadata={op_name="jit(step)/backend/vmap()/gather"}
+  %g.2 = f32[4]{0} gather(%p0, %p1), metadata={op_name="jit(step)/backend/gather"}
+  ROOT %c.3 = f32[4]{0} add(%g.1, %g.2), metadata={op_name="jit(step)/combine/add"}
+}
+
+ENTRY %main.5 (x: f32[8]) -> f32[4] {
+  %x = f32[8]{0} parameter(0), metadata={op_name="x"}
+  %sort.1 = s32[8]{0} sort(%x), metadata={op_name="jit(step)/dispatch/jit(argsort)/sort"}
+  %fusion.9 = f32[4]{0} fusion(%x, %sort.1), kind=kLoop, calls=%fused_computation.9
+  %wrapped_reduce = s32[] fusion(%sort.1), kind=kLoop, calls=%wrapped_computation
+  %mul.4 = f32[] multiply(%x, %x), metadata={op_name="jit(step)/mul"}
+  ROOT %tuple.7 = (f32[4]{0}) tuple(%fusion.9)
+}
+"""
+
+
+def test_op_scopes_reads_optimized_hlo_text():
+    """Names keep their .N; the scope is the element after jit(step)/; a
+    fusion without its own scope takes the majority of its calls=
+    computation, through to_apply= where that computation has none; a bare
+    op ran under no scope."""
+    from repro.obs import op_scopes
+    assert op_scopes(HLO) == {
+        "add.2": "switch", "g.1": "backend", "g.2": "backend",
+        "c.3": "combine", "sort.1": "dispatch", "fusion.9": "backend",
+        "reduce.3": "switch", "wrapped_reduce": "switch"}
+
+
+def test_step_scopes_of_a_compiled_cpu_step(hybrid_setup):
+    """The fused step compiled on the CPU: every scope of the step appears,
+    an instruction with its own op_name takes the element after jit(step)/,
+    and a fusion without one (XLA's wrapped reduce-windows) takes a scope
+    from the computations it calls."""
+    import re
+    art, small, big, xte, yte = hybrid_setup
+    srv = HybridServer(art, _backend(big, "fused"), threshold=0.7,
+                       capacity=128)
+    scopes = srv.step_scopes(256)
+    assert set(scopes.values()) == {"switch", "dispatch", "backend",
+                                    "combine"}
+    text = srv._step.lower(srv.artifact,
+                           jax.ShapeDtypeStruct((256, 5), jnp.float32),
+                           jnp.float32(0.7)).compile().as_text()
+    borrowed = 0
+    for line in text.splitlines():
+        m = re.match(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (.*)$", line)
+        if m is None:
+            continue
+        name, rest = m.groups()
+        own = re.search(r'op_name="jit\(step\)/(\w+)/', rest)
+        if own:
+            assert scopes[name] == own.group(1), line
+        elif " fusion(" in rest and name in scopes:
+            borrowed += 1
+    assert borrowed > 0
+    assert any(re.search(r"\.\d+$", n) for n in scopes)

@@ -10,8 +10,8 @@ The contracts under test (DESIGN.md §14):
   ``snapshot()`` that never raises;
 * ``RollupWindows`` — per-N-samples keyed windows with element-wise
   list folding (class-count vectors) and partial-window flush;
-* ``StageTimer`` / ``SampledSync`` — per-stage accumulation and the
-  every-N sync cadence (0 = never);
+* ``StageTimer`` / ``span`` — per-stage accumulation, each stage of an
+  ``Observability`` inside a span of its name;
 * ``DriftMonitor`` — frozen per-key baselines, the three detectors
   (conf_collapse, frac_handled_drop, class_mix_shift), min_packets
   guard, reset;
@@ -40,8 +40,8 @@ from repro.netsim.features import flow_features
 from repro.netsim.ingest import IngestStats, LatencyRecorder, replay_source
 from repro.netsim.packets import synth_trace
 from repro.obs import (DriftConfig, DriftMonitor, EventBus, EventSchemaError,
-                       MetricsRegistry, Observability, RollupWindows,
-                       SampledSync, StageTimer, validate_event_log)
+                       STAGES, MetricsRegistry, Observability,
+                       RollupWindows, StageTimer, validate_event_log)
 from repro.serving.faults import (CLOSED, BackendFault, FaultPolicy,
                                   FaultStats, FaultyBackend, GuardedBackend)
 from repro.serving.shard_serving import ShardedStreamingServer
@@ -157,7 +157,7 @@ def test_rollup_windows_close_flush_and_vector_fold():
 
 
 # ---------------------------------------------------------------------------
-# StageTimer / SampledSync
+# StageTimer
 # ---------------------------------------------------------------------------
 
 def test_stage_timer_accumulates():
@@ -172,13 +172,6 @@ def test_stage_timer_accumulates():
     assert summ["megastep"]["n"] == 2
     assert summ["megastep"]["total_s"] == pytest.approx(1.0)
     assert summ["h2d"]["max_ms"] == pytest.approx(250.0)
-
-
-def test_sampled_sync_cadence():
-    assert [SampledSync(0).due() for _ in range(5)] == [False] * 5
-    s = SampledSync(3)
-    assert [s.due() for _ in range(7)] == [False, False, True,
-                                           False, False, True, False]
 
 
 # ---------------------------------------------------------------------------
@@ -451,18 +444,65 @@ def test_obs_stats_as_dict_contract(obs_setup):
         assert isinstance(cls().as_dict(), dict)
 
 
-def test_obs_sampled_sync_and_stage_timing_bit_identical(obs_setup):
-    """sync_every changes when the host waits, never a value; the stage
-    timers see the megastep and the synced stage."""
+def test_obs_sampled_sync_and_stage_timing_bit_identical(obs_setup,
+                                                          monkeypatch):
+    """The serving loop's stages are timed, each inside a span of its
+    name, with the predictions bit-identical and no device sync added:
+    outside the reads of ``StreamStats`` that the rollups make by design,
+    the loop waits on and reads back device arrays exactly as often with
+    obs attached as without. Waits and reads are counted on the array
+    itself: ``block_until_ready`` and the batched wait, the buffer that
+    ``np.asarray`` takes, and ``_value``, which ``device_get``, ``int``
+    and ``float`` go through."""
+    import sys
+    from collections import Counter
+
+    from jax._src import api
+    from jax._src import array as jarray
+
+    import repro.obs
+    from repro.serving.stream_serving import StreamStats
     trace, art, backend = obs_setup
     kw = dict(n_buckets=N_BUCKETS, window=128, chunk_windows=4)
+    opened, syncs = [], Counter()
+    real_span = repro.obs.span
+    monkeypatch.setattr(repro.obs, "span", lambda name, **ids: (
+        opened.append(name), real_span(name, **ids))[1])
+
+    def counted(kind, real):
+        def f(*a):
+            fr = sys._getframe(1)
+            while fr is not None and not isinstance(fr.f_locals.get("self"),
+                                                    StreamStats):
+                fr = fr.f_back
+            if fr is None:
+                syncs[kind] += 1
+            return real(*a)
+        return f
+
+    value = jarray.ArrayImpl._value
+    monkeypatch.setattr(jarray.ArrayImpl, "_value",
+                        property(counted("read", value.fget)))
+    monkeypatch.setattr(jarray.ArrayImpl, "__buffer__", counted(
+        "read", jarray.ArrayImpl.__buffer__))
+    monkeypatch.setattr(jarray.ArrayImpl, "block_until_ready", counted(
+        "wait", jarray.ArrayImpl.block_until_ready))
+    monkeypatch.setattr(api.xc, "batched_block_until_ready", counted(
+        "wait", api.xc.batched_block_until_ready))
     ref, _ = StreamingHybridServer(art, backend, **kw).serve_trace(trace)
-    obs = Observability(rollup_every=2, sync_every=2)
+    without_obs = syncs.copy()
+    syncs.clear()
+    obs = Observability(rollup_every=2)
     srv = StreamingHybridServer(art, backend, obs=obs, **kw)
     preds, _ = srv.serve_trace(trace)
+    with_obs = syncs.copy()
     np.testing.assert_array_equal(np.asarray(preds), np.asarray(ref))
-    assert obs.timer.count("megastep") > 0
-    assert obs.timer.count("megastep_synced") > 0
+    n = obs.timer.count("megastep")
+    assert n > 0 and opened.count("megastep") == n
+    assert set(obs.timer.stages) <= set(STAGES) and set(opened) == set(
+        obs.timer.stages)
+    assert without_obs["read"] > 0          # the counting sees the loop
+    assert with_obs == without_obs
 
 
 def test_obs_drift_fires_on_class_mix_shift_trace(obs_setup):
