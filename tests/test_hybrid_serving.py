@@ -231,6 +231,29 @@ def test_classify_counts_calls(hybrid_setup, path):
     assert srv._fused_ok is (path == "fused")
 
 
+@pytest.mark.parametrize("path", ["fused", "two_phase"])
+def test_classify_with_a_depth8_backend_matches_the_heap_walk(
+        hybrid_setup, monkeypatch, path):
+    """A depth-8 backend forest walked with level-wise selects (inside the
+    fused step, or on the host between the two phases) gives the same
+    predictions as the same server on the heap walk."""
+    from repro.ml import trees
+    art, small, big, xte, yte = hybrid_setup
+    deep = fit_random_forest(np.asarray(xte), np.asarray(yte), n_classes=2,
+                             n_trees=16, max_depth=8, seed=2, max_features=5)
+    assert deep.depth == 8 <= trees.SELECT_MAX_DEPTH
+    preds = []
+    for bound in (trees.SELECT_MAX_DEPTH, 0):
+        monkeypatch.setattr(trees, "SELECT_MAX_DEPTH", bound)
+        srv = HybridServer(art, _backend(deep, path), threshold=0.95,
+                           capacity=256)
+        pred, stats = srv.classify(xte[:1000])
+        assert srv._fused_ok is (path == "fused")
+        assert 0 < stats.backend_rows <= 256
+        preds.append(np.asarray(pred))
+    np.testing.assert_array_equal(preds[0], preds[1])
+
+
 HLO = """HloModule jit_step, entry_computation_layout={(f32[8]{0})->f32[4]{0}}
 
 %region_0.1 (a: s32[], b: s32[]) -> s32[] {
