@@ -5,12 +5,17 @@ sweeps (Figs 10-11). ``dispatch``/``combine`` are the serving form: the
 low-confidence subset is *compacted* (MoE-dispatch style) so the expensive
 backend only sees the forwarded queries — the load-reduction benefit in
 collective/compute terms.
+
+Every form takes ``switch_features``: the static column indices the switch
+parses out of a wider row (the finance deployment's 5 of 130). The switch
+classifies those columns; the backend gets the whole row. ``None`` means
+the switch reads every column.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable
+from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -28,10 +33,22 @@ class HybridResult:
     fraction_handled: jax.Array
 
 
+def switch_columns(x: jax.Array,
+                   switch_features: Optional[Sequence[int]]) -> jax.Array:
+    """The columns of ``x`` (N, F) the switch parses: ``x`` itself for
+    ``None``, else the listed columns in order, as static slices (no
+    gather)."""
+    if switch_features is None:
+        return x
+    return jnp.concatenate([x[:, c:c + 1] for c in switch_features], axis=1)
+
+
 def hybrid_predict(art: TableArtifact, backend_fn: Callable, x,
-                   threshold: float) -> HybridResult:
+                   threshold: float,
+                   switch_features: Optional[Sequence[int]] = None
+                   ) -> HybridResult:
     """Dense hybrid: backend evaluated everywhere, selected where needed."""
-    sw_pred, conf = table_predict(art, x)
+    sw_pred, conf = table_predict(art, switch_columns(x, switch_features))
     handled = conf >= threshold
     be_pred = backend_fn(x)
     pred = jnp.where(handled, sw_pred, be_pred)
@@ -181,13 +198,15 @@ def backpatch_pending(pending: jax.Array, backend_pred: jax.Array,
 
 
 def hybrid_serve(art: TableArtifact, backend_fn: Callable, x,
-                 threshold: float, capacity: int):
+                 threshold: float, capacity: int,
+                 switch_features: Optional[Sequence[int]] = None):
     """Serving-form hybrid with bounded backend batch.
 
-    backend_fn receives exactly ``capacity`` rows (padded with whatever rows
-    were not forwarded) — a static shape, so the backend step stays jittable.
+    backend_fn receives exactly ``capacity`` full-width rows (padded with
+    whatever rows were not forwarded) — a static shape, so the backend
+    step stays jittable.
     """
-    sw_pred, conf = table_predict(art, x)
+    sw_pred, conf = table_predict(art, switch_columns(x, switch_features))
     fwd = conf < threshold
     buf, idx, valid = dispatch(x, fwd, capacity)
     be_pred = backend_fn(buf)

@@ -5,6 +5,10 @@ trains the small switch model + large backend on the synthetic use-case
 data, stands up the HybridServer, runs batched requests through it, and
 prints the paper's telemetry (fraction handled, misclassification).
 
+``--use-case finance`` is the paper's finance deployment: the switch
+parses 5 of a trade's 130 features (``switch_features``) and the XGBoost
+backend scores the forwarded trades on all 130, both in one fused step.
+
 ``--backend lm`` scores forwarded requests with a (smoke-sized) LM
 backend instead of the full ensemble — the integration path where the
 low-confidence subset is re-encoded as tokens for an LM scorer.
@@ -20,10 +24,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.mapping import map_tree_ensemble
-from repro.kernels.ops import fused_classify
 from repro.ml.metrics import accuracy, precision_recall_f1
 from repro.ml.trees import (fit_random_forest, fit_xgboost,
-                            predict_margin_xgboost, predict_tree_ensemble)
+                            predict_tree_ensemble)
 from repro.serving.hybrid_serving import HybridServer
 
 
@@ -32,8 +35,7 @@ def build_usecase(name: str, n=20000, seed=0):
         from repro.data.unsw_like import make_unsw_like, train_test_split
         x, y = make_unsw_like(n, seed=seed, n_features=5)
         return train_test_split(x, y)
-    from repro.data.janestreet_like import (SWITCH_FEATURES,
-                                            make_janestreet_like,
+    from repro.data.janestreet_like import (make_janestreet_like,
                                             train_test_split)
     x, y = make_janestreet_like(n, seed=seed)
     return train_test_split(x, y)
@@ -53,11 +55,11 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     xtr, ytr, xte, yte = build_usecase(args.use_case)
+    switch_features = None
     if args.use_case == "finance":
         from repro.data.janestreet_like import SWITCH_FEATURES
-        xsw_tr, xsw_te = xtr[:, SWITCH_FEATURES], xte[:, SWITCH_FEATURES]
-    else:
-        xsw_tr, xsw_te = xtr, xte
+        switch_features = SWITCH_FEATURES
+    xsw_tr = xtr if switch_features is None else xtr[:, switch_features]
 
     # small switch model (paper Table 3 "Medium") + big backend
     small = fit_random_forest(xsw_tr, ytr, n_classes=2,
@@ -66,17 +68,11 @@ def main(argv=None):
     art = map_tree_ensemble(small, xsw_tr.shape[1])
 
     if args.backend == "ensemble":
+        # the backend scores the forwarded rows on every feature
         big = fit_xgboost(xtr, ytr, n_trees=60, max_depth=6)
-        full_dim = xtr.shape[1]
 
-        def backend_fn(rows_sw):
-            # the backend sees the full feature vector; look rows up by
-            # matching switch features is not possible -> in serving the
-            # forwarded request carries its full payload. Here we emulate
-            # by an index side-channel set per batch (see loop below).
-            idx = backend_fn.idx
-            margins = predict_margin_xgboost(big, backend_fn.full_rows[idx])
-            return (margins > 0).astype(jnp.int32)
+        def backend_fn(rows):
+            return predict_tree_ensemble(big, rows)
     else:
         from repro.configs import get_smoke_config
         from repro.models import model as M
@@ -91,33 +87,15 @@ def main(argv=None):
             logits, _ = M.prefill(params, cfg, {"tokens": toks})
             return (logits[:, 0] > logits[:, 1]).astype(jnp.int32)
 
-    # the ensemble backend reads per-batch side-channels (idx/full_rows on
-    # the function object): it must not be traced into the fused step
     server = HybridServer(art, backend_fn, threshold=args.threshold,
                           capacity=args.capacity,
-                          fuse=False if args.backend == "ensemble" else None)
+                          switch_features=switch_features)
 
-    n = xsw_te.shape[0]
+    n = xte.shape[0]
     preds = []
     t0 = time.time()
     for lo in range(0, n - args.batch + 1, args.batch):
-        rows = xsw_te[lo:lo + args.batch]
-        if args.backend == "ensemble":
-            backend_fn.full_rows = jnp.asarray(xte[lo:lo + args.batch])
-            # dispatch indices are produced inside classify; recompute here
-            # with the SAME switch realization the server resolved
-            # (server.use_pallas) so idx matches bit for bit — a
-            # different kernel path could order the dispatch differently
-            # and silently score the wrong full-feature rows
-            sw_pred, conf = fused_classify(server.artifact, rows,
-                                           use_pallas=server.use_pallas,
-                                           tiles=server.tiles)
-            from repro.core.hybrid import dispatch
-            fwd = conf < args.threshold
-            buf, idx, valid = dispatch(jnp.asarray(rows, jnp.float32), fwd,
-                                       args.capacity)
-            backend_fn.idx = idx
-        pred, stats = server.classify(rows)
+        pred, stats = server.classify(xte[lo:lo + args.batch])
         preds.append(np.asarray(pred))
     pred = np.concatenate(preds)
     m = len(pred)

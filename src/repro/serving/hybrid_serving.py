@@ -37,17 +37,23 @@ gather), ``backend`` and ``combine``; TPU op events carry no scope, so
 ``HybridServer.step_scopes`` maps the compiled step's instructions to
 them. The host counter ``calls`` numbers the requests and gives the
 spans their id; it adds no device sync.
+
+Wide rows: where the switch parses a few columns of a wider request (the
+finance deployment's 5 of 130 features), ``switch_features`` names them.
+The step then classifies those columns inside ``switch`` and ``dispatch``
+gathers the full-width forwarded rows into the backend buffer, so one
+fused step serves a switch and a backend that read different columns.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.artifact import TableArtifact, finalize_artifact
-from repro.core.hybrid import combine, dispatch
+from repro.core.hybrid import combine, dispatch, switch_columns
 from repro.kernels.ops import fused_classify
 from repro.kernels.tuning import (DEFAULT_TILES, TileConfig, autotune_tiles,
                                   resolve_use_pallas)
@@ -106,8 +112,16 @@ class HybridServer:
                  *, threshold: float = 0.7, capacity: int = 256,
                  use_pallas: Optional[bool] = None, autotune: bool = False,
                  donate: bool = False, tiles: Optional[TileConfig] = None,
-                 fuse: Optional[bool] = None):
+                 fuse: Optional[bool] = None,
+                 switch_features: Optional[Sequence[int]] = None):
         """backend_fn: (rows (capacity, F)) -> class predictions (capacity,).
+
+        switch_features: the column indices of a request row that the
+        switch parses, in the artifact's feature order (static: they are
+        baked into the step). The artifact then classifies
+        ``x[:, switch_features]`` and ``backend_fn`` receives the whole
+        (capacity, F) rows. None (the default): the switch reads every
+        column, and the rows are the artifact's width.
 
         use_pallas: None (the default) resolves by platform — the Pallas
         kernels on TPU, the bit-identical XLA references elsewhere
@@ -138,6 +152,12 @@ class HybridServer:
         self._backend_fn = backend_fn
         self._capacity = capacity
         self.threshold = threshold
+        if switch_features is not None:
+            switch_features = tuple(int(c) for c in switch_features)
+            if len(switch_features) != self.artifact.n_features:
+                raise ValueError(
+                    f"switch_features names {len(switch_features)} columns; "
+                    f"the artifact reads {self.artifact.n_features}")
         self.use_pallas = use_pallas = resolve_use_pallas(use_pallas)
         # tiles only steer the Pallas kernels; sweeping them for the XLA
         # reference path would be pure init latency
@@ -148,8 +168,9 @@ class HybridServer:
 
         def switch_only(art, x, threshold):
             with jax.named_scope("switch"):
-                sw_pred, conf = fused_classify(art, x, use_pallas=use_pallas,
-                                               tiles=self.tiles)
+                sw_pred, conf = fused_classify(
+                    art, switch_columns(x, switch_features),
+                    use_pallas=use_pallas, tiles=self.tiles)
             with jax.named_scope("dispatch"):
                 fwd = conf < threshold
                 buf, idx, valid = dispatch(x, fwd, capacity)
@@ -211,14 +232,17 @@ class HybridServer:
             pred = self._combine(sw_pred, be_pred, idx, valid)
         return pred, HybridStats(frac, rows, self.capacity)
 
-    def step_scopes(self, n_rows: int) -> dict:
+    def step_scopes(self, n_rows: int,
+                    n_features: Optional[int] = None) -> dict:
         """{instruction name: scope} of the fused step compiled for
-        ``n_rows`` rows (``repro.obs.op_scopes``): how a profiler trace's
-        op events of ``jit_step`` split into ``switch``, ``dispatch``,
-        ``backend`` and ``combine``. Compiles (or loads) the step; call it
-        outside a timed window."""
-        x = jax.ShapeDtypeStruct((n_rows, self.artifact.n_features),
-                                 jnp.float32)
+        ``n_rows`` rows of ``n_features`` columns (by default the
+        artifact's; a server with ``switch_features`` needs the request
+        width) (``repro.obs.op_scopes``): how a profiler trace's op events
+        of ``jit_step`` split into ``switch``, ``dispatch``, ``backend``
+        and ``combine``. Compiles (or loads) the step; call it outside a
+        timed window."""
+        x = jax.ShapeDtypeStruct(
+            (n_rows, n_features or self.artifact.n_features), jnp.float32)
         compiled = self._step.lower(self.artifact, x,
                                     jnp.float32(self.threshold)).compile()
         return op_scopes(compiled.as_text())

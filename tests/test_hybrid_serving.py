@@ -327,3 +327,106 @@ def test_step_scopes_of_a_compiled_cpu_step(hybrid_setup):
             borrowed += 1
     assert borrowed > 0
     assert any(re.search(r"\.\d+$", n) for n in scopes)
+
+
+# ---------------------------------------------------------------------------
+# switch_features: the switch parses a few columns of a wider row
+# ---------------------------------------------------------------------------
+
+WIDE = 12
+COLS = (7, 2, 11, 4, 0)              # the artifact's features 0..4, in order
+
+
+def _wide_rows(x5, seed=0):
+    """(N, WIDE) rows holding the 5 switch features at ``COLS`` and noise
+    elsewhere."""
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(len(x5), WIDE)).astype(np.float32)
+    x[:, list(COLS)] = np.asarray(x5, np.float32)
+    return x
+
+
+def _wide_backend(path):
+    """A backend that reads columns the switch never parses: 1 where
+    column 1 exceeds column 3, fused or on the host."""
+    if path == "fused":
+        return lambda r: (r[:, 1] > r[:, 3]).astype(jnp.int32)
+    return lambda r: np.asarray(np.asarray(r)[:, 1] > np.asarray(r)[:, 3],
+                                np.int32)
+
+
+def _wide_reference(art, x, tau, cap):
+    """numpy: the switch's answer on the switch columns; the first ``cap``
+    rows below ``tau`` take the backend's answer on the whole row."""
+    sw, conf = (np.asarray(a) for a in table_predict(art, x[:, list(COLS)]))
+    fwd = conf < tau
+    sent = fwd & (np.cumsum(fwd) <= cap)
+    return np.where(sent, (x[:, 1] > x[:, 3]).astype(np.int32), sw), sent
+
+
+@pytest.mark.parametrize("path", ["fused", "two_phase"])
+def test_switch_features_match_a_numpy_reference(hybrid_setup, path):
+    """The switch classifies the columns ``switch_features`` names and the
+    backend answers on the whole forwarded rows, on either path; the
+    dense and serving forms of ``core.hybrid`` agree."""
+    from repro.core.hybrid import switch_columns
+    art, small, big, xte, yte = hybrid_setup
+    x = _wide_rows(xte[:700])
+    tau, cap = 0.95, 64
+    srv = HybridServer(art, _wide_backend(path), threshold=tau,
+                       capacity=cap, switch_features=COLS)
+    pred, stats = srv.classify(x)
+    assert srv._fused_ok is (path == "fused")
+    want, sent = _wide_reference(art, x, tau, cap)
+    assert 0 < sent.sum() == stats.backend_rows <= cap
+    assert (want != np.asarray(table_predict(art, x[:, list(COLS)])[0])
+            ).any()                     # the backend's answers count
+    np.testing.assert_array_equal(np.asarray(pred), want)
+    np.testing.assert_array_equal(np.asarray(switch_columns(x, COLS)),
+                                  x[:, list(COLS)])
+    served, _ = hybrid_serve(art, _wide_backend("fused"), x, tau, cap,
+                             switch_features=COLS)
+    np.testing.assert_array_equal(np.asarray(served), want)
+    dense = hybrid_predict(art, _wide_backend("fused"), x, tau,
+                           switch_features=COLS)
+    np.testing.assert_array_equal(
+        np.asarray(dense.pred),
+        np.where(np.asarray(dense.handled), np.asarray(dense.switch_pred),
+                 (x[:, 1] > x[:, 3]).astype(np.int32)))
+
+
+def test_switch_features_none_is_the_server_without_them(hybrid_setup):
+    """``switch_features=None`` lowers to the very step of a server built
+    without the keyword, one with no column slicing in it; naming every
+    column in order gives the same answers through a step that slices."""
+    art, small, big, xte, yte = hybrid_setup
+    be = _backend(big, "fused")
+    x = jax.ShapeDtypeStruct((256, 5), jnp.float32)
+    tau = jnp.float32(0.7)
+    servers = [HybridServer(art, be, capacity=128, **kw)
+               for kw in ({}, {"switch_features": None},
+                          {"switch_features": range(5)})]
+    texts = [s._step.lower(s.artifact, x, tau).as_text() for s in servers]
+    assert texts[0] == texts[1]
+    assert "concatenate" in texts[2] and texts[2] != texts[0]
+    preds = [np.asarray(s.classify(xte[:256])[0]) for s in servers]
+    np.testing.assert_array_equal(preds[0], preds[1])
+    np.testing.assert_array_equal(preds[0], preds[2])
+
+
+def test_switch_features_must_match_the_artifact(hybrid_setup):
+    art, small, big, xte, yte = hybrid_setup
+    with pytest.raises(ValueError, match="switch_features names 4"):
+        HybridServer(art, _backend(big, "fused"), switch_features=(0, 1, 2, 3))
+
+
+def test_step_scopes_of_a_wide_step(hybrid_setup):
+    """A server with ``switch_features`` maps the step compiled for its
+    request width: the column slice under ``switch``, the backend's reads
+    of the unparsed columns under ``backend``."""
+    art, small, big, xte, yte = hybrid_setup
+    srv = HybridServer(art, _wide_backend("fused"), threshold=0.7,
+                       capacity=128, switch_features=COLS)
+    scopes = srv.step_scopes(256, WIDE)
+    assert set(scopes.values()) == {"switch", "dispatch", "backend",
+                                    "combine"}

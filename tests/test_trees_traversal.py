@@ -174,3 +174,50 @@ def test_backend_shape_takes_the_gather_free_walk():
     assert _gathers(_dense_forest(8, n_trees=32), 1024) == 0
     deep = _dense_forest(trees.SELECT_MAX_DEPTH + 1, n_trees=32)
     assert _gathers(deep, 1024) > 0
+
+
+def test_boosted_margins_in_row_blocks_match_a_numpy_heap_walk(monkeypatch):
+    """The finance backend's shape, cut in trees: XGBoost trees of depth 11
+    on 130 features, walked by the selects with the row blocks engaged
+    (128-row blocks, the last padded), against a numpy heap walk with
+    float32 compares and a float64 sum. The leaves agree exactly; the
+    margins to the float32 rounding of an 8-term sum. Rows hold +-inf,
+    NaN and -0.0 but no subnormal, which XLA flushes to zero and numpy
+    does not."""
+    depth, n_feat = 11, 130
+    dense = _dense_forest(depth, n_trees=8, n_feat=n_feat, seed=3)
+    ens = dataclasses.replace(dense, leaf=dense.leaf[..., :1] - 4.0,
+                              kind="xgb", learning_rate=0.05,
+                              base_score=0.1)
+    rng = np.random.default_rng(11)
+    x = rng.normal(size=(300, n_feat)).astype(np.float32)
+    x[::3] = rng.choice(np.asarray(ens.thresh).ravel(), x[::3].shape)
+    mask = rng.random(x.shape) < 0.05
+    x[mask] = rng.choice(np.float32([np.inf, -np.inf, np.nan, -0.0, 0.0]),
+                         int(mask.sum()))
+    x[np.abs(x) < np.finfo(np.float32).tiny] = 0.0
+    monkeypatch.setattr(trees, "_BLOCK_ELEMS", 1)      # 128-row blocks
+    idx = np.asarray(tree_leaf_indices(ens, x))
+    margin = np.asarray(predict_margin_xgboost(ens, x))
+    pred = np.asarray(predict_tree_ensemble(ens, x))
+
+    feat, thresh = np.asarray(ens.feat), np.asarray(ens.thresh)
+    leaf = np.asarray(ens.leaf)[..., 0].astype(np.float64)
+    rows = np.arange(len(x))
+    want_idx = np.empty_like(idx)
+    total = np.zeros(len(x))
+    size = np.zeros(len(x))
+    for t in range(ens.n_trees):
+        node = np.zeros(len(x), np.int64)
+        for _ in range(depth):
+            node = 2 * node + 1 + (x[rows, feat[t, node]] > thresh[t, node])
+        want_idx[t] = node - feat.shape[1]
+        total += leaf[t, want_idx[t]]
+        size += np.abs(leaf[t, want_idx[t]])
+    want = 0.1 + 0.05 * total
+    np.testing.assert_array_equal(idx, want_idx)
+    band = ens.n_trees * 2.0 ** -24 * 0.05 * size + 2.0 ** -24 * 0.1
+    assert (np.abs(margin - want) <= band).all()
+    sure = np.abs(want) > band
+    assert sure.mean() > 0.9 and 0 < pred[sure].mean() < 1
+    np.testing.assert_array_equal(pred[sure], (want > 0)[sure])
