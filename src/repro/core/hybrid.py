@@ -4,7 +4,8 @@
 sweeps (Figs 10-11). ``dispatch``/``combine`` are the serving form: the
 low-confidence subset is *compacted* (MoE-dispatch style) so the expensive
 backend only sees the forwarded queries — the load-reduction benefit in
-collective/compute terms.
+collective/compute terms — and ``backend_over_blocks`` runs the backend
+only on the buffer's row blocks that hold them.
 
 Every form takes ``switch_features``: the static column indices the switch
 parses out of a wider row (the finance deployment's 5 of 130). The switch
@@ -71,6 +72,47 @@ def dispatch(x: jax.Array, forward_mask: jax.Array, capacity: int):
     valid = forward_mask[idx]
     buf = x[idx]
     return buf, idx, valid
+
+
+# Rows per backend block in the fused step: the TPU's lane width, which is
+# also the smallest row block of ``repro.ml.trees``' walk. A dispatch buffer
+# whose capacity is not a multiple of it is one block.
+BACKEND_BLOCK = 128
+
+
+def backend_block(capacity: int) -> int:
+    """Rows per backend block of a ``capacity``-row dispatch buffer."""
+    return BACKEND_BLOCK if capacity % BACKEND_BLOCK == 0 else capacity
+
+
+def backend_over_blocks(backend_fn: Callable, buf: jax.Array,
+                        rows: jax.Array) -> jax.Array:
+    """``backend_fn`` over the blocks of ``buf`` (capacity, F) that hold
+    forwarded rows -> answers (capacity, ...).
+
+    ``dispatch`` puts the forwarded rows first, so the ``rows`` valid ones
+    (a traced count: no recompile per count) lie in the first
+    ``ceil(rows / B)`` blocks of ``B = backend_block(capacity)`` rows. A
+    loop runs ``backend_fn`` on each such (B, F) block, traced once; the
+    other blocks keep zero answers, which ``combine`` never reads (their
+    ``valid`` is False). With ``rows`` 0 the backend does not run."""
+    cap = buf.shape[0]
+    b = backend_block(cap)
+
+    def block(r):
+        return jnp.asarray(backend_fn(r))
+
+    out = jax.eval_shape(
+        block, jax.ShapeDtypeStruct((b,) + buf.shape[1:], buf.dtype))
+    ans = jnp.zeros((cap,) + out.shape[1:], out.dtype)
+
+    def body(i, ans):
+        start = i * b
+        return jax.lax.dynamic_update_slice_in_dim(
+            ans, block(jax.lax.dynamic_slice_in_dim(buf, start, b)), start,
+            axis=0)
+
+    return jax.lax.fori_loop(0, (rows + b - 1) // b, body, ans)
 
 
 def combine(switch_pred: jax.Array, backend_pred_subset: jax.Array,

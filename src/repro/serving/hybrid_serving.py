@@ -10,10 +10,13 @@ Request path:
      fixed-capacity buffer (same machinery as MoE token dispatch) and only
      that buffer hits the BACKEND — either the full-grown ensemble
      (paper-faithful) or an LM scorer. This is the paper's back-end load
-     reduction, in batch-size form: the expensive model runs on
-     capacity-many rows, not on the full batch. ``HybridStats.backend_rows``
-     / ``capacity`` is the share of that work that is used: the fill of
-     the dispatch buffer.
+     reduction, in batch-size form: the expensive model runs on blocks
+     of ``B`` rows of that buffer (``core.hybrid.backend_block``: 128
+     where ``capacity`` is a multiple of it, else the whole buffer), only
+     on those that hold forwarded rows, never on the full batch.
+     ``HybridStats.backend_rows`` / ``capacity`` is the fill of the
+     dispatch buffer, ``HybridStats.backend_blocks`` the blocks the
+     backend ran.
 
 Zero-sync single-dispatch path: switch classify + dispatch + backend +
 combine are ONE jitted, buffer-donating function, so a classify() is a
@@ -53,7 +56,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.artifact import TableArtifact, finalize_artifact
-from repro.core.hybrid import combine, dispatch, switch_columns
+from repro.core.hybrid import (backend_block, backend_over_blocks, combine,
+                               dispatch, switch_columns)
 from repro.kernels.ops import fused_classify
 from repro.kernels.tuning import (DEFAULT_TILES, TileConfig, autotune_tiles,
                                   resolve_use_pallas)
@@ -68,8 +72,11 @@ class HybridStats:
     does, which keeps classify() fully asynchronous.
 
     ``backend_rows`` is the dispatch layer's counter: the rows of the
-    call that reached the backend, at most ``capacity``, the buffer the
-    backend evaluates whole.
+    call that reached the backend, at most ``capacity``.
+    ``backend_blocks`` is derived from it: the blocks of ``B`` rows
+    (``core.hybrid.backend_block``) the fused step ran the backend on,
+    ``ceil(backend_rows / B)``, at most ``capacity // B``. (The
+    two-phase path still hands the backend the whole buffer.)
     """
 
     __slots__ = ("_fraction_handled", "_backend_rows", "capacity")
@@ -87,6 +94,10 @@ class HybridStats:
     def backend_rows(self) -> int:
         return int(self._backend_rows)
 
+    @property
+    def backend_blocks(self) -> int:
+        return -(-self.backend_rows // backend_block(self.capacity))
+
     def as_arrays(self):
         """(fraction_handled, backend_rows) as device arrays — no sync."""
         return self._fraction_handled, self._backend_rows
@@ -94,6 +105,7 @@ class HybridStats:
     def __repr__(self):
         return (f"HybridStats(fraction_handled={self.fraction_handled:.3f}, "
                 f"backend_rows={self.backend_rows}, "
+                f"backend_blocks={self.backend_blocks}, "
                 f"capacity={self.capacity})")
 
 
@@ -114,13 +126,18 @@ class HybridServer:
                  donate: bool = False, tiles: Optional[TileConfig] = None,
                  fuse: Optional[bool] = None,
                  switch_features: Optional[Sequence[int]] = None):
-        """backend_fn: (rows (capacity, F)) -> class predictions (capacity,).
+        """backend_fn: (rows (B, F)) -> class predictions (B,), run by the
+        fused step on each block of ``B`` rows of the (capacity, F)
+        dispatch buffer that holds forwarded rows, and on none when no
+        row is forwarded (``core.hybrid.backend_over_blocks``; ``B`` is
+        128 where ``capacity`` is a multiple of it, else ``capacity``).
+        The two-phase path hands it the whole (capacity, F) buffer.
 
         switch_features: the column indices of a request row that the
         switch parses, in the artifact's feature order (static: they are
         baked into the step). The artifact then classifies
         ``x[:, switch_features]`` and ``backend_fn`` receives the whole
-        (capacity, F) rows. None (the default): the switch reads every
+        rows. None (the default): the switch reads every
         column, and the rows are the artifact's width.
 
         use_pallas: None (the default) resolves by platform — the Pallas
@@ -182,7 +199,7 @@ class HybridServer:
             sw_pred, buf, idx, valid, frac, rows = switch_only(art, x,
                                                                threshold)
             with jax.named_scope("backend"):
-                be_pred = jnp.asarray(backend_fn(buf))
+                be_pred = backend_over_blocks(backend_fn, buf, rows)
             with jax.named_scope("combine"):
                 pred = combine(sw_pred, be_pred, idx, valid)
             return pred, frac, rows

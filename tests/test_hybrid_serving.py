@@ -430,3 +430,61 @@ def test_step_scopes_of_a_wide_step(hybrid_setup):
     scopes = srv.step_scopes(256, WIDE)
     assert set(scopes.values()) == {"switch", "dispatch", "backend",
                                     "combine"}
+
+
+# ---------------------------------------------------------------------------
+# the backend runs only on the dispatch buffer's blocks of forwarded rows
+# ---------------------------------------------------------------------------
+
+def _rows_forwarding(art, xte, n, k, tau, seed=0):
+    """n rows of ``xte`` in a seeded order, exactly k of them below ``tau``
+    at the switch (rows repeat where ``xte`` has too few)."""
+    conf = np.asarray(table_predict(art, xte)[1])
+    low, high = np.flatnonzero(conf < tau), np.flatnonzero(conf >= tau)
+    idx = np.concatenate([np.resize(low, k), np.resize(high, n - k)])
+    return np.asarray(xte)[np.random.default_rng(seed).permutation(idx)]
+
+
+@pytest.mark.parametrize("cap,k", [
+    (256, 0), (256, 1), (256, 127), (256, 128), (256, 129), (256, 256),
+    (1024, 0), (1024, 1), (1024, 127), (1024, 128), (1024, 129),
+    (1024, 1024), (200, 0), (200, 1), (200, 200)])
+def test_backend_runs_on_the_filled_blocks_only(hybrid_setup, cap, k):
+    """With k rows forwarded to a ``cap``-row buffer, the fused step traces
+    the backend once per block shape, (128, F) or (cap, F) where ``cap`` is
+    no multiple of 128, and answers as ``hybrid_serve``'s whole-buffer
+    evaluation bit for bit; ``backend_over_blocks`` leaves the blocks past
+    the forwarded rows unevaluated, and ``backend_blocks`` counts the
+    blocks that ran."""
+    from repro.core.hybrid import backend_over_blocks, dispatch
+    art, small, big, xte, yte = hybrid_setup
+    tau = 0.9
+    b = 128 if cap % 128 == 0 else cap
+    x = _rows_forwarding(art, xte, cap + 64, k, tau)
+    shapes = []
+
+    def backend(rows):
+        # a class per row that the switch never answers, so each backend
+        # answer shows where combine put it
+        shapes.append(rows.shape)
+        return 2 + (jnp.abs(rows[:, 0] * 1e3).astype(jnp.int32) % 5)
+
+    srv = HybridServer(art, backend, threshold=tau, capacity=cap)
+    pred, stats = srv.classify(x)
+    assert srv._fused_ok and set(shapes) == {(b, 5)}
+    want, _ = hybrid_serve(art, backend, x, tau, cap)
+    np.testing.assert_array_equal(np.asarray(pred), np.asarray(want))
+    assert int((np.asarray(pred) >= 2).sum()) == k
+    assert stats.backend_rows == int(stats.as_arrays()[1]) == k
+    assert stats.backend_blocks == -(-k // b) <= cap // b
+    if k == 0:
+        sw, _ = table_predict(art, x)
+        np.testing.assert_array_equal(np.asarray(pred), np.asarray(sw))
+
+    fwd = np.asarray(table_predict(art, x)[1]) < tau
+    buf, _, _ = dispatch(jnp.asarray(x), jnp.asarray(fwd), cap)
+    ans = np.asarray(jax.jit(lambda r, n: backend_over_blocks(
+        backend, r, n))(buf, jnp.int32(k)))
+    ran = -(-k // b) * b
+    np.testing.assert_array_equal(ans[:ran], np.asarray(backend(buf))[:ran])
+    assert not ans[ran:].any()
