@@ -2,9 +2,10 @@
 
 ``hybrid_predict`` is the analysis-friendly dense form used by the paper's
 sweeps (Figs 10-11). ``dispatch``/``combine`` are the serving form: the
-low-confidence subset is *compacted* (MoE-dispatch style) so the expensive
-backend only sees the forwarded queries — the load-reduction benefit in
-collective/compute terms — and ``backend_over_blocks`` runs the backend
+low-confidence subset is *compacted* (a stable sort puts the forwarded
+rows first in a fixed-capacity buffer) so the expensive backend only sees
+the forwarded queries — the load-reduction benefit in collective/compute
+terms — and ``backend_over_blocks`` runs the backend
 only on the buffer's row blocks that hold them.
 
 Every form takes ``switch_features``: the static column indices the switch

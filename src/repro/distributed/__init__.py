@@ -1,10 +1,7 @@
-"""Distribution: mesh-aware sharding rules, specs for params/batches/caches."""
+"""Distribution: the flow-table tier's ('shard', 'data') mesh and shardings."""
 
 from repro.distributed.sharding import (
-    param_specs,
-    batch_specs,
-    cache_specs,
-    opt_state_specs,
+    as_flow_mesh,
     named_sharding_tree,
     flow_shard_mesh,
     flow_table_sharding,

@@ -82,15 +82,3 @@ def stream_update_ref(regs, bucket, ts, length, is_fwd, valid, *,
     new_regs = jnp.stack(new)
     return new_regs, new_regs[:, bucket]
 
-
-def decode_attention_int8_ref(q, k_q, k_s, v_q, v_s, valid, *, scale):
-    """Dense oracle for the int8-KV decode-attention kernel.
-
-    q (B,G,M,hd) f32; k_q/v_q (B,S,G,hd) int8; k_s/v_s (B,S,G,1) f32;
-    valid (B,S) -> (B,G,M,hd) f32."""
-    k = k_q.astype(jnp.float32) * k_s                      # (B,S,G,hd)
-    v = v_q.astype(jnp.float32) * v_s
-    sc = jnp.einsum("bgmd,bsgd->bgms", q, k) * scale
-    sc = jnp.where(valid[:, None, None, :] > 0.5, sc, -1e30)
-    w = jax.nn.softmax(sc, axis=-1)
-    return jnp.einsum("bgms,bsgd->bgmd", w, v)

@@ -1,1 +1,2 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve drivers."""
+"""Launchers: the persistent compile cache (`compile_cache`) and the
+hybrid serving CLI (`serve`)."""

@@ -1,4 +1,4 @@
-"""Serving launcher: hybrid IIsy switch tier + LM/ensemble backend.
+"""Serving launcher: hybrid IIsy switch tier + tree-ensemble backend.
 
 ``python -m repro.launch.serve --use-case anomaly --threshold 0.7``
 trains the small switch model + large backend on the synthetic use-case
@@ -8,10 +8,6 @@ prints the paper's telemetry (fraction handled, misclassification).
 ``--use-case finance`` is the paper's finance deployment: the switch
 parses 5 of a trade's 130 features (``switch_features``) and the XGBoost
 backend scores the forwarded trades on all 130, both in one fused step.
-
-``--backend lm`` scores forwarded requests with a (smoke-sized) LM
-backend instead of the full ensemble — the integration path where the
-low-confidence subset is re-encoded as tokens for an LM scorer.
 """
 
 from __future__ import annotations
@@ -19,8 +15,6 @@ from __future__ import annotations
 import argparse
 import time
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.mapping import map_tree_ensemble
@@ -49,8 +43,6 @@ def main(argv=None):
     ap.add_argument("--capacity", type=int, default=1024)
     ap.add_argument("--switch-trees", type=int, default=10)
     ap.add_argument("--switch-depth", type=int, default=5)
-    ap.add_argument("--backend", default="ensemble",
-                    choices=["ensemble", "lm"])
     ap.add_argument("--batch", type=int, default=2048)
     args = ap.parse_args(argv)
 
@@ -67,25 +59,11 @@ def main(argv=None):
                               max_depth=args.switch_depth, seed=0)
     art = map_tree_ensemble(small, xsw_tr.shape[1])
 
-    if args.backend == "ensemble":
-        # the backend scores the forwarded rows on every feature
-        big = fit_xgboost(xtr, ytr, n_trees=60, max_depth=6)
+    # the backend scores the forwarded rows on every feature
+    big = fit_xgboost(xtr, ytr, n_trees=60, max_depth=6)
 
-        def backend_fn(rows):
-            return predict_tree_ensemble(big, rows)
-    else:
-        from repro.configs import get_smoke_config
-        from repro.models import model as M
-        cfg = get_smoke_config("qwen3-4b")
-        params = M.init_model(cfg, jax.random.PRNGKey(0))
-
-        def backend_fn(rows_sw):
-            # encode each forwarded row as a token sequence (feature
-            # binning as tokens) and read class from the last logit sign
-            toks = (jnp.abs(rows_sw[:, :8]) * 7).astype(jnp.int32) % cfg.vocab_size
-            toks = jnp.pad(toks, ((0, 0), (0, max(0, 8 - toks.shape[1]))))
-            logits, _ = M.prefill(params, cfg, {"tokens": toks})
-            return (logits[:, 0] > logits[:, 1]).astype(jnp.int32)
+    def backend_fn(rows):
+        return predict_tree_ensemble(big, rows)
 
     server = HybridServer(art, backend_fn, threshold=args.threshold,
                           capacity=args.capacity,
@@ -101,8 +79,7 @@ def main(argv=None):
     m = len(pred)
     acc = accuracy(yte[:m], pred)
     p, r, f1 = precision_recall_f1(yte[:m], pred)
-    print(f"use_case={args.use_case} backend={args.backend} "
-          f"tau={args.threshold}")
+    print(f"use_case={args.use_case} tau={args.threshold}")
     print(f"acc={acc:.4f} precision={p:.4f} recall={r:.4f} f1={f1:.4f}")
     print(f"handled_at_switch={stats.fraction_handled:.3f} "
           f"backend_rows/batch={stats.backend_rows}/{args.batch} "

@@ -20,10 +20,7 @@ Hardware model (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
 
 from __future__ import annotations
 
-import dataclasses
-import math
 import re
-from typing import Optional
 
 HW = {
     "peak_flops": 197e12,    # bf16 / chip
@@ -124,15 +121,3 @@ def roofline_terms(cost: dict, coll: dict, *, hw: dict = HW) -> dict:
             # fraction of ideal: if perfectly overlapped, step time = max term
             "overlap_roofline_frac": bound / total if total > 0 else 0.0}
 
-
-def model_flops(cfg, n_params_total: int, n_params_active: int,
-                shape_kind: str, seq_len: int, global_batch: int) -> float:
-    """MODEL_FLOPS = 6*N*D (train) or 2*N*D (inference), N = active params.
-
-    D = processed tokens: seq*batch for train/prefill, batch for decode."""
-    n = n_params_active
-    if shape_kind == "train":
-        return 6.0 * n * seq_len * global_batch
-    if shape_kind == "prefill":
-        return 2.0 * n * seq_len * global_batch
-    return 2.0 * n * global_batch          # decode: one token per request

@@ -1,7 +1,7 @@
-"""Serving runtime: cache plumbing, prefill/decode engine, hybrid tier
-(batch and streaming)."""
+"""Serving runtime: the hybrid tier per request (`HybridServer`) and on
+the streaming flow table (`StreamingHybridServer`, sharded:
+`ShardedStreamingServer`)."""
 
-from repro.serving.engine import ServeEngine, greedy_generate
 from repro.serving.hybrid_serving import HybridServer
 from repro.serving.stream_serving import StreamingHybridServer, StreamStats
 from repro.serving.shard_serving import ShardedStreamingServer

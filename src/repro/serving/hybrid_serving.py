@@ -7,9 +7,9 @@ Request path:
   3. confidence >= tau  -> answered at the switch (dropped / tagged /
      fast-pathed per use case);
   4. confidence <  tau  -> the low-confidence subset is *compacted* into a
-     fixed-capacity buffer (same machinery as MoE token dispatch) and only
-     that buffer hits the BACKEND — either the full-grown ensemble
-     (paper-faithful) or an LM scorer. This is the paper's back-end load
+     fixed-capacity buffer (a stable sort of the forwarded rows to the
+     front) and only that buffer hits the BACKEND, the full-grown model
+     (paper-faithful). This is the paper's back-end load
      reduction, in batch-size form: the expensive model runs on blocks
      of ``B`` rows of that buffer (``core.hybrid.backend_block``: 128
      where ``capacity`` is a multiple of it, else the whole buffer), only
@@ -19,7 +19,7 @@ Request path:
      backend ran.
 
 Zero-sync single-dispatch path: switch classify + dispatch + backend +
-combine are ONE jitted, buffer-donating function, so a classify() is a
+combine are ONE jitted function, so a classify() is a
 single device dispatch with no host round-trips in between. Telemetry
 (fraction handled, backend occupancy — Figs 10-11's sweep quantities)
 returns as device arrays wrapped in a lazy HybridStats: nothing blocks on
@@ -112,9 +112,8 @@ class HybridStats:
 class HybridServer:
     # Hot-path auditor contract (repro.analysis.hotpath): the batch step
     # is audited for zero-sync and dtype layout with an empty donation
-    # set — donate=True is documented below as unaliasable for the
-    # current output shapes (jax would silently prune it, which is
-    # exactly what the auditor exists to reject on the streaming tiers).
+    # set — it carries no state, and its outputs (pred (N,) i32 + scalar
+    # telemetry) could alias no (N, F) f32 input.
     AUDIT_CONTRACTS = (
         {"attr": "_step", "donate": (), "probe": "batch",
          "collectives": {}},
@@ -123,7 +122,7 @@ class HybridServer:
     def __init__(self, artifact: TableArtifact, backend_fn: Callable,
                  *, threshold: float = 0.7, capacity: int = 256,
                  use_pallas: Optional[bool] = None, autotune: bool = False,
-                 donate: bool = False, tiles: Optional[TileConfig] = None,
+                 tiles: Optional[TileConfig] = None,
                  fuse: Optional[bool] = None,
                  switch_features: Optional[Sequence[int]] = None):
         """backend_fn: (rows (B, F)) -> class predictions (B,), run by the
@@ -149,12 +148,7 @@ class HybridServer:
         autotune=True sweeps kernel tile sizes once for this artifact shape
         (cached per shape+backend; only meaningful — and only run — when
         the kernels are on, since the XLA reference path ignores tile
-        configs). donate=True marks the input batch
-        donatable to the fused step; with the current step outputs (pred
-        (N,) i32 + scalar telemetry) nothing can alias an (N, F) f32 input,
-        so this is off by default — enable it if you extend the step to
-        return row-shaped outputs. A caller that passes an already-float32
-        jax.Array then cedes that buffer (standard donation semantics).
+        configs).
 
         fuse: None probes on the first classify whether backend_fn traces
         into the single-dispatch step; False forces the two-phase path.
@@ -204,7 +198,7 @@ class HybridServer:
                 pred = combine(sw_pred, be_pred, idx, valid)
             return pred, frac, rows
 
-        self._step = jax.jit(step, donate_argnums=(1,) if donate else ())
+        self._step = jax.jit(step)
         self._switch_only = jax.jit(switch_only)
         self._combine = jax.jit(combine)
 
@@ -235,7 +229,7 @@ class HybridServer:
                     return pred, HybridStats(frac, rows, self.capacity)
                 except (jax.errors.JAXTypeError, TypeError):
                     # backend_fn is not traceable; tracing failed before
-                    # any execution, so x was not consumed by the donation
+                    # any execution
                     self._fused_ok = False
             if self._fused_ok:
                 pred, frac, rows = self._step(self.artifact, x, tau)

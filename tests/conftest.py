@@ -1,5 +1,6 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — tests run on 1 CPU device;
-only launch/dryrun.py requests 512 placeholder devices."""
+the sharded tier's multi-device cases need
+XLA_FLAGS=--xla_force_host_platform_device_count=4 set before JAX starts."""
 
 import numpy as np
 import pytest

@@ -25,15 +25,21 @@ from repro.kernels.ops import _pad_batch, fused_classify
 from repro.kernels.tuning import TileConfig
 
 
-def _fit_artifact(model, xtr, ytr):
+def _fit_model(model, xtr, ytr):
+    """-> (artifact, fitted model): 4 trees of depth 4 (the isolation
+    forest 6), so every agg mode stays small."""
     from benchmarks.common import fit_and_map
     if model == "IForest":
         from repro.ml.trees import fit_isolation_forest
         ens = fit_isolation_forest(np.asarray(xtr), n_trees=6, max_depth=4,
                                    seed=0)
-        return map_tree_ensemble(ens, xtr.shape[1])
-    _, art, _ = fit_and_map(model, xtr, ytr, n_trees=4, max_depth=4)
-    return art
+        return map_tree_ensemble(ens, xtr.shape[1]), ens
+    _, art, m = fit_and_map(model, xtr, ytr, n_trees=4, max_depth=4)
+    return art, m
+
+
+def _fit_artifact(model, xtr, ytr):
+    return _fit_model(model, xtr, ytr)[0]
 
 
 ALL_MODELS = ("DT", "RF", "XGB", "IForest", "SVM", "Bayes", "KMeans")

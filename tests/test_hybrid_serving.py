@@ -1,4 +1,4 @@
-"""Hybrid tier + serving engine integration tests."""
+"""Hybrid tier integration tests: hybrid_serve and HybridServer."""
 
 import jax
 import jax.numpy as jnp
@@ -121,35 +121,6 @@ def test_hybrid_server_untraceable_backend_falls_back(hybrid_setup):
     assert srv._fused_ok is False
     assert pred.shape == (100,)
     assert stats.backend_rows == 32             # tau=2.0 forwards everything
-
-
-def test_greedy_generate_deterministic():
-    from repro.configs import get_smoke_config
-    from repro.models import model as M
-    from repro.serving.engine import greedy_generate
-    cfg = get_smoke_config("yi-6b")
-    params = M.init_model(cfg, jax.random.PRNGKey(0))
-    batch = {"tokens": jnp.asarray([[1, 2, 3, 4, 5, 6, 7, 8]], jnp.int32)}
-    o1 = greedy_generate(cfg, params, batch, n_new=6)
-    o2 = greedy_generate(cfg, params, batch, n_new=6)
-    np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
-
-
-def test_generate_matches_rerun_prefill():
-    """Token t generated with caches == argmax of prefill(prompt+prefix)."""
-    from repro.configs import get_smoke_config
-    from repro.models import model as M
-    from repro.serving.engine import greedy_generate
-    cfg = get_smoke_config("h2o-danube-1.8b")
-    params = M.init_model(cfg, jax.random.PRNGKey(0))
-    prompt = jnp.asarray([[3, 1, 4, 1, 5, 9, 2, 6]], jnp.int32)
-    out = greedy_generate(cfg, params, {"tokens": prompt}, n_new=3,
-                          cache_dtype=jnp.float32)
-    # recompute token 2 by prefilling prompt + out[:, :2]
-    full = jnp.concatenate([prompt, out[:, :2]], axis=1)
-    logits, _ = M.prefill(params, cfg, {"tokens": full})
-    expect = jnp.argmax(logits, axis=-1)
-    np.testing.assert_array_equal(np.asarray(out[:, 2]), np.asarray(expect))
 
 
 def test_servers_resolve_use_pallas_by_platform(hybrid_setup, monkeypatch):
