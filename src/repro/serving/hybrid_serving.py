@@ -30,9 +30,15 @@ are detected on the first classify and served by a two-phase fallback:
 jitted switch+dispatch, host backend call, jitted combine — still one
 host hop fewer than the pre-refactor path.
 
+Threshold: tau lives on the device as an ``f32[]`` scalar, made once
+when the threshold is set (``HybridServer.threshold``) and passed to the
+step as a traced argument, so a call moves only its rows to the device
+and a new tau never recompiles. The host counter ``threshold_uploads``
+counts the scalars made.
+
 Tracing: every classify opens host spans (``repro.obs.span``) that share
-the call's id, ``hybrid.h2d`` around the conversion of the rows and tau
-to device arrays and ``hybrid.dispatch`` around the step (on the
+the call's id, ``hybrid.h2d`` around the transfer of the rows to the
+device and ``hybrid.dispatch`` around the step (on the
 two-phase path around the whole call, with ``hybrid.backend_host``
 inside it around the host backend). Inside the step, ``jax.named_scope``
 marks ``switch`` (fused classify), ``dispatch`` (threshold, sort,
@@ -54,6 +60,7 @@ from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.artifact import TableArtifact, finalize_artifact
 from repro.core.hybrid import (backend_block, backend_over_blocks, combine,
@@ -159,9 +166,11 @@ class HybridServer:
         self.artifact = finalize_artifact(artifact)
         # capacity and backend_fn are baked into the jitted step: frozen.
         # threshold is a *traced* argument, so it stays tunable per call
-        # (sweeping tau never recompiles).
+        # (sweeping tau never recompiles); the setter keeps it on the
+        # device as the f32[] scalar every classify passes to the step.
         self._backend_fn = backend_fn
         self._capacity = capacity
+        self.threshold_uploads = 0              # device scalars made for tau
         self.threshold = threshold
         if switch_features is not None:
             switch_features = tuple(int(c) for c in switch_features)
@@ -203,6 +212,22 @@ class HybridServer:
         self._combine = jax.jit(combine)
 
     @property
+    def threshold(self) -> float:
+        """tau: rows whose switch confidence falls below it go to the
+        backend."""
+        return self._threshold
+
+    @threshold.setter
+    def threshold(self, value: float):
+        """Puts a new tau on the device, once: every classify passes that
+        f32[] scalar to the step, which takes it as a traced argument."""
+        if self.threshold_uploads and value == self._threshold:
+            return
+        self._threshold = value
+        self._tau = jax.device_put(np.float32(value))
+        self.threshold_uploads += 1
+
+    @property
     def capacity(self) -> int:
         """Backend buffer size. Frozen: it fixes the compiled shapes —
         build a new server to change it."""
@@ -220,7 +245,7 @@ class HybridServer:
         self.calls += 1
         with span("hybrid.h2d", call=call):
             x = jnp.asarray(x, jnp.float32)
-            tau = jnp.float32(self.threshold)
+        tau = self._tau
         with span("hybrid.dispatch", call=call):
             if self._fused_ok is None:
                 try:
@@ -254,8 +279,7 @@ class HybridServer:
         timed window."""
         x = jax.ShapeDtypeStruct(
             (n_rows, n_features or self.artifact.n_features), jnp.float32)
-        compiled = self._step.lower(self.artifact, x,
-                                    jnp.float32(self.threshold)).compile()
+        compiled = self._step.lower(self.artifact, x, self._tau).compile()
         return op_scopes(compiled.as_text())
 
     def update_tables(self, artifact: TableArtifact):

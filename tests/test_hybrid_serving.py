@@ -459,3 +459,104 @@ def test_backend_runs_on_the_filled_blocks_only(hybrid_setup, cap, k):
     ran = -(-k // b) * b
     np.testing.assert_array_equal(ans[:ran], np.asarray(backend(buf))[:ran])
     assert not ans[ran:].any()
+
+
+# ---------------------------------------------------------------------------
+# tau: one device scalar per threshold, none per call
+# ---------------------------------------------------------------------------
+
+def _device_backend(big):
+    """A backend that runs on the device (jitted), so the two-phase path
+    moves nothing to the device either; ``fuse=False`` forces that path."""
+    return jax.jit(lambda r: predict_tree_ensemble(big, r))
+
+
+@pytest.mark.parametrize("path", ["fused", "two_phase"])
+def test_classify_of_rows_on_the_device_transfers_nothing(hybrid_setup, path):
+    """Once compiled, a call whose rows are already on the device makes no
+    host-to-device transfer on either path: tau is the server's device
+    scalar, not a value rebuilt per call."""
+    art, small, big, xte, yte = hybrid_setup
+    srv = HybridServer(art, _device_backend(big), threshold=0.9,
+                       capacity=128,
+                       fuse=None if path == "fused" else False)
+    x = jax.device_put(np.asarray(xte[:256], np.float32))
+    want_pred, want_stats = srv.classify(x)
+    with jax.transfer_guard_host_to_device("disallow"):
+        for _ in range(2):
+            pred, stats = srv.classify(x)
+            jax.block_until_ready((pred, stats.as_arrays()))
+    assert srv._fused_ok is (path == "fused")
+    np.testing.assert_array_equal(np.asarray(pred), np.asarray(want_pred))
+    assert stats.backend_rows == want_stats.backend_rows > 0
+
+
+@pytest.mark.parametrize("path", ["fused", "two_phase"])
+def test_a_new_threshold_serves_like_a_fresh_server(hybrid_setup, path):
+    """Setting ``threshold`` between calls gives the predictions and stats
+    of a server built at that tau, and the step is traced and compiled
+    once across the whole sweep."""
+    art, small, big, xte, yte = hybrid_setup
+    traced = []
+
+    def backend(rows):
+        traced.append(rows.shape)
+        return predict_tree_ensemble(big, rows)
+
+    kw = dict(capacity=128, fuse=None if path == "fused" else False)
+    srv = HybridServer(art, backend, threshold=0.7, **kw)
+    x = xte[:512]
+    rows, n_traced = set(), []
+    for tau in (0.7, 0.9, 0.55, 0.99, 0.7):
+        srv.threshold = tau
+        pred, stats = srv.classify(x)
+        n_traced.append(len(traced))
+        fresh_pred, fresh = HybridServer(art, _backend(big, "fused"),
+                                         threshold=tau, **kw).classify(x)
+        np.testing.assert_array_equal(np.asarray(pred),
+                                      np.asarray(fresh_pred))
+        assert stats.fraction_handled == fresh.fraction_handled
+        assert stats.backend_rows == fresh.backend_rows
+        rows.add(stats.backend_rows)
+    assert len(rows) > 2                        # the sweep moved the split
+    jitted = srv._step if path == "fused" else srv._switch_only
+    assert jitted._cache_size() == 1
+    if path == "fused":                         # (two-phase: runs eagerly)
+        assert len(set(n_traced)) == 1          # traced by the first call only
+
+
+def test_threshold_uploads_counts_the_changes_of_threshold(hybrid_setup):
+    """One device scalar per new threshold: none per call, none for
+    setting the value the server already holds."""
+    art, small, big, xte, yte = hybrid_setup
+    srv = HybridServer(art, _backend(big, "fused"), threshold=0.7,
+                       capacity=128)
+    assert srv.threshold_uploads == 1
+    for _ in range(3):
+        srv.classify(xte[:256])
+    assert (srv.calls, srv.threshold_uploads) == (3, 1)
+    srv.threshold = 0.7
+    assert srv.threshold_uploads == 1
+    srv.threshold = 0.9
+    srv.classify(xte[:256])
+    assert (srv.threshold, srv.threshold_uploads) == (0.9, 2)
+    srv.threshold = 0.7
+    assert srv.threshold_uploads == 3
+    assert srv._tau.dtype == jnp.float32 and srv._tau.shape == ()
+    assert float(srv._tau) == np.float32(0.7)
+
+
+def test_the_step_lowers_as_with_a_tau_made_per_call(hybrid_setup):
+    """The scalar ``classify`` passes is a traced f32[]: the step lowers
+    to the same text, hash for hash, as with ``jnp.float32(tau)`` made per
+    call and as with an abstract f32[] argument."""
+    import hashlib
+    art, small, big, xte, yte = hybrid_setup
+    srv = HybridServer(art, _backend(big, "fused"), threshold=0.7,
+                       capacity=128)
+    x = jax.ShapeDtypeStruct((256, 5), jnp.float32)
+    hashes = {hashlib.sha256(srv._step.lower(srv.artifact, x, tau)
+                             .as_text().encode()).hexdigest()
+              for tau in (srv._tau, jnp.float32(0.7),
+                          jax.ShapeDtypeStruct((), jnp.float32))}
+    assert len(hashes) == 1
