@@ -52,6 +52,9 @@ def _leaf_walk(feat, thresh, x, depth):
 def map_tree_ensemble(ens: TreeEnsemble, n_features: int, *,
                       action_bits: int = 16,
                       max_decision_entries: int = 2_000_000) -> TableArtifact:
+    if not ens.complete:
+        raise ValueError("the mapping tool reads complete heap trees; "
+                         "node-array trees cannot be mapped")
     feat = np.asarray(ens.feat)        # (T, H)
     thresh = np.asarray(ens.thresh)    # (T, H)
     leaf = np.asarray(ens.leaf)        # (T, L, C)

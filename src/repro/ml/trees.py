@@ -1,24 +1,36 @@
-"""Histogram-based tree learners with fixed-shape, jit-compatible training.
+"""Histogram-based tree learners with fixed-shape, jit-compatible training,
+and the walks that serve any tree ensemble.
 
-All trees are *complete* binary trees of a fixed ``max_depth`` stored as flat
-heap arrays, which keeps every shape static (level-wise growth, the
+The learners grow *complete* binary trees of a fixed ``max_depth`` stored as
+flat heap arrays, which keeps every shape static (level-wise growth, the
 XGBoost/LightGBM histogram method). A node that should not split gets the
 sentinel threshold ``+inf`` so every sample routes left and the right subtree
 becomes unreachable.
 
-Layout (per tree):
+Heap layout (per tree; children of node ``i`` are ``2i+1`` and ``2i+2``):
   feat   : (2**D - 1,) int32   feature index per internal heap node
   thresh : (2**D - 1,) float32 ``x <= thresh`` routes left; +inf = no split
   leaf   : (2**D, C)   float32 leaf payload (class counts, boosting weight,
                                or isolation sample count)
 
-The IIsy mapping tool (repro.core.mapping) consumes exactly these arrays.
+Node-array layout (per tree, for trees that are not complete, such as a
+fully grown forest; scikit-learn's own layout with the children side by
+side), every array padded to the forest's largest tree:
+  feat   : (M,)    int32   feature index per node
+  thresh : (M,)    float32 ``x <= thresh`` routes left
+  left   : (M,)    int32   the left child; the right one is ``left + 1``.
+                           A leaf, and a pad node, holds its own index with
+                           ``thresh = +inf``, so a row that reached it stays
+  leaf   : (M, C)  float32 payload per node (read at the leaves)
+
+The IIsy mapping tool (repro.core.mapping) consumes the heap arrays.
 """
 
 from __future__ import annotations
 
 import dataclasses
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -30,24 +42,36 @@ NEG_INF = -jnp.inf
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class TreeEnsemble:
-    """A bag of complete trees plus ensemble metadata."""
+    """A bag of trees plus ensemble metadata: complete trees in heap layout
+    (``left`` None), or trees of any shape as node arrays."""
 
-    feat: jax.Array        # (T, 2**D - 1) int32
-    thresh: jax.Array      # (T, 2**D - 1) float32
-    leaf: jax.Array        # (T, 2**D, C) float32
+    feat: jax.Array        # (T, 2**D - 1) int32, or (T, M) node arrays
+    thresh: jax.Array      # (T, 2**D - 1) float32, or (T, M)
+    leaf: jax.Array        # (T, 2**D, C) float32, or (T, M, C)
     kind: str = dataclasses.field(metadata=dict(static=True), default="rf")
     # 'dt' | 'rf' | 'xgb' | 'iforest'
     base_score: float = dataclasses.field(metadata=dict(static=True), default=0.0)
     learning_rate: float = dataclasses.field(metadata=dict(static=True), default=1.0)
     n_classes: int = dataclasses.field(metadata=dict(static=True), default=2)
+    left: Optional[jax.Array] = None   # (T, M) int32 for node arrays
+    # levels from the root to the deepest leaf; the heap's own by default
+    depth: Optional[int] = dataclasses.field(metadata=dict(static=True),
+                                             default=None)
+
+    def __post_init__(self):
+        if self.depth is None:
+            if self.left is not None:
+                raise ValueError("node-array trees need their depth")
+            self.depth = int(np.log2(self.feat.shape[-1] + 1))
 
     @property
     def n_trees(self) -> int:
         return self.feat.shape[0]
 
     @property
-    def depth(self) -> int:
-        return int(np.log2(self.feat.shape[1] + 1))
+    def complete(self) -> bool:
+        """Whether the trees are complete heaps (no ``left`` array)."""
+        return self.left is None
 
 
 # ---------------------------------------------------------------------------
@@ -368,37 +392,52 @@ def fit_isolation_forest(x, *, n_trees=32, max_depth=6, n_bins=64,
 # prediction
 # ---------------------------------------------------------------------------
 
-# Deepest tree that ``tree_leaf_indices`` walks level by level with selects;
-# deeper trees take the heap walk. On a TPU v5e (32 trees, 1,024 rows) the
-# selects took 41, 126 and 852 us of device time at depths 8, 10 and 12,
-# the heap walk 6.9, 8.7 and 10.5 ms: 12 is the deepest depth measured,
-# and the selects still won there. With 60 trees on 130 features (depths
-# 6 and 8, 1,024 rows) they won 160x and 149x: the value pick's growth
-# with the features does not move the choice.
+# Deepest complete tree that ``tree_leaf_indices`` walks level by level
+# with selects; deeper trees, and trees that are not complete, take the
+# node walk. On a TPU v5e (32 trees, 1,024 rows) the selects took 41, 126
+# and 852 us of device time at depths 8, 10 and 12, the heap walk (the
+# per-tree gather walk the node walk replaced) 6.9, 8.7 and 10.5 ms: 12 is
+# the deepest depth measured, and the selects still won there. With 60
+# trees on 130 features (depths 6 and 8, 1,024 rows) they won 160x and
+# 149x: the value pick's growth with the features does not move the
+# choice.
 SELECT_MAX_DEPTH = 12
 
 
-def _selects(depth: int) -> bool:
-    """Whether trees ``depth`` deep take the level-wise selects, for the
-    walk and the leaf read alike."""
-    return depth <= SELECT_MAX_DEPTH
+def _selects(ens: TreeEnsemble) -> bool:
+    """Whether ``ens`` takes the level-wise selects, for the walk and the
+    leaf read alike: complete trees up to ``SELECT_MAX_DEPTH`` deep."""
+    return ens.complete and ens.depth <= SELECT_MAX_DEPTH
 
 
-def _leaf_index(feat, thresh, x, depth):
-    """Heap walk, fixed depth. x: (N, F); feat/thresh: (H,). -> (N,) leaf id.
+def _node_walk(ens: TreeEnsemble, x) -> jax.Array:
+    """Node walk, ``ens.depth`` levels. x (N, F) -> (T, N) index into the
+    node axis of ``ens.leaf``.
 
-    Three row-dependent gathers per level (node feature, node threshold,
-    the row's value), which a TPU runs nearly element by element; the
-    work grows with ``depth``. The path for trees deeper than
-    ``SELECT_MAX_DEPTH``."""
-    n = x.shape[0]
-    node = jnp.zeros((n,), jnp.int32)
-    for _ in range(depth):
-        f = feat[node]
-        t = thresh[node]
-        xv = jnp.take_along_axis(x, f[:, None], axis=1)[:, 0]
-        node = 2 * node + 1 + (xv > t).astype(jnp.int32)
-    return node - ((1 << depth) - 1)
+    Each level gathers, for every (tree, row), its node's feature,
+    threshold and child, and the row's value of that feature; ``xv > t``
+    takes the right child, ``left + 1``. Node arrays hold their children
+    (a leaf its own index, with threshold +inf, so a row that reached one
+    stays there); a complete heap's children are ``2i+1`` and ``2i+2``,
+    and its walk ends on leaf row ``i - (2**D - 1)``. The work is
+    ``depth`` gathers per (tree, row), whatever the trees' shape."""
+    n_trees, m = ens.feat.shape
+    n, n_feat = x.shape
+    at0 = (jnp.arange(n_trees, dtype=jnp.int32) * m)[:, None]     # (T, 1)
+    feat, thresh = ens.feat.reshape(-1), ens.thresh.reshape(-1)
+    left = None if ens.complete else ens.left.reshape(-1)
+    xs = x.reshape(-1)
+    row0 = jnp.arange(n, dtype=jnp.int32) * n_feat                # (N,)
+
+    def level(_, node):
+        at = at0 + node
+        xv = xs[row0 + feat[at]]
+        child = 2 * node + 1 if left is None else left[at]
+        return child + (xv > thresh[at]).astype(jnp.int32)
+
+    node = jax.lax.fori_loop(0, ens.depth, level,
+                             jnp.zeros((n_trees, n), jnp.int32))
+    return node - m if ens.complete else node
 
 
 def _pick(hit, table):
@@ -444,7 +483,7 @@ def _level_select(feat, thresh, x):
     Each (tree, row) holds its node ``j`` within the level; the node's
     feature and threshold are one-hot picks over the level's static node
     set, the row's value a one-hot pick over the F features, so the
-    compare is the heap walk's bit for bit. Rows stay on the minor axis
+    compare is the node walk's bit for bit. Rows stay on the minor axis
     and the widest intermediate is (max(2**(D-1), F), T, N), never the
     whole tree. Work grows with 2**depth and with F."""
     depth = (feat.shape[1] + 1).bit_length() - 1
@@ -462,21 +501,21 @@ def _level_select(feat, thresh, x):
 
 
 def tree_leaf_indices(ens: TreeEnsemble, x) -> jax.Array:
-    """(T, N) leaf index per tree.
+    """(T, N) index into each tree's ``leaf`` rows: the leaf of a complete
+    tree, the node of node arrays.
 
-    Trees up to ``SELECT_MAX_DEPTH`` deep take ``_level_select`` (about
-    2**depth + depth * F selects per tree and row, no gather); deeper
-    trees take the heap walk ``_leaf_index`` (depth gathers, whose
-    per-element cost wins once 2**depth outgrows it). The choice rests on the ensemble's static
-    shape alone, and both give the same indices."""
+    Complete trees up to ``SELECT_MAX_DEPTH`` deep take ``_level_select``
+    (about 2**depth + depth * F selects per tree and row, no gather);
+    every other ensemble takes the node walk ``_node_walk`` (depth
+    gathers, whose per-element cost wins once 2**depth outgrows it). The
+    choice rests on the ensemble's static form alone, and on complete
+    trees both give the same indices."""
     x = jnp.asarray(x, jnp.float32)
-    depth = ens.depth
-    if _selects(depth):
-        width = max(1 << max(depth - 1, 0), x.shape[1])  # nodes or features
+    if _selects(ens):
+        width = max(1 << max(ens.depth - 1, 0), x.shape[1])  # nodes or features
         return _in_row_blocks(partial(_level_select, ens.feat, ens.thresh),
                               x, ens.n_trees * width)
-    return jax.vmap(lambda f, t: _leaf_index(f, t, x, depth))(ens.feat,
-                                                              ens.thresh)
+    return _node_walk(ens, x)
 
 
 @jax.jit
@@ -489,11 +528,12 @@ def _leaf_pick(leaf, leaf_idx):
     return jnp.moveaxis(vals, 0, -1)                         # (T, N, C)
 
 
-def _leaf_values(leaf, leaf_idx):
-    """leaf (T, L, C), leaf_idx (T, N) -> (T, N, C), each tree's leaf row:
-    picked where ``tree_leaf_indices`` takes the selects, gathered where
-    it takes the heap walk."""
-    if not _selects(leaf.shape[1].bit_length() - 1):
+def _leaf_values(ens: TreeEnsemble, leaf_idx):
+    """leaf_idx (T, N) -> (T, N, C), each tree's ``leaf`` row: picked
+    where ``tree_leaf_indices`` takes the selects, gathered where it takes
+    the node walk."""
+    leaf = ens.leaf
+    if not _selects(ens):
         return jnp.take_along_axis(leaf, leaf_idx[:, :, None], axis=1)
     return _in_row_blocks(lambda i: _leaf_pick(leaf, i.T), leaf_idx.T,
                           leaf.size)
@@ -502,14 +542,14 @@ def _leaf_values(leaf, leaf_idx):
 def predict_proba_tree_ensemble(ens: TreeEnsemble, x) -> jax.Array:
     """Mean per-tree class distribution (DT/RF). -> (N, C)."""
     leaf_idx = tree_leaf_indices(ens, x)               # (T, N)
-    counts = _leaf_values(ens.leaf, leaf_idx)          # (T, N, C)
+    counts = _leaf_values(ens, leaf_idx)          # (T, N, C)
     probs = counts / jnp.maximum(counts.sum(-1, keepdims=True), 1e-9)
     return probs.mean(axis=0)
 
 
 def predict_margin_xgboost(ens: TreeEnsemble, x) -> jax.Array:
     leaf_idx = tree_leaf_indices(ens, x)
-    w = _leaf_values(ens.leaf, leaf_idx)[..., 0]                  # (T, N)
+    w = _leaf_values(ens, leaf_idx)[..., 0]                  # (T, N)
     return ens.base_score + ens.learning_rate * w.sum(axis=0)
 
 
@@ -521,7 +561,7 @@ def _c_factor(n):
 def predict_iforest_score(ens: TreeEnsemble, x, subsample=256) -> jax.Array:
     """Anomaly score in (0, 1); higher = more anomalous."""
     leaf_idx = tree_leaf_indices(ens, x)
-    size = _leaf_values(ens.leaf, leaf_idx)[..., 0]
+    size = _leaf_values(ens, leaf_idx)[..., 0]
     depth = ens.depth
     path = depth + jnp.where(size > 1, _c_factor(size), 0.0)
     e_path = path.mean(axis=0)
@@ -537,3 +577,66 @@ def predict_tree_ensemble(ens: TreeEnsemble, x) -> jax.Array:
     if ens.kind == "iforest":
         return (predict_iforest_score(ens, x) > 0.5).astype(jnp.int32)
     raise ValueError(ens.kind)
+
+
+# ---------------------------------------------------------------------------
+# scikit-learn forests
+# ---------------------------------------------------------------------------
+
+def _f32_at_most(t: np.ndarray) -> np.ndarray:
+    """The largest float32 not greater than each float64 ``t``: for any
+    float32 ``x``, ``x <= result`` decides as ``x <= t``."""
+    r = t.astype(np.float32)
+    return np.where(r.astype(np.float64) > t,
+                    np.nextafter(r, np.float32(-np.inf)), r)
+
+
+def _sklearn_nodes(tree) -> tuple:
+    """One fitted ``tree_`` renumbered breadth first, each node's children
+    side by side. -> (feat, thresh, left, value) per node."""
+    cl, cr = tree.children_left, tree.children_right
+    new = np.zeros(cl.shape[0], np.int64)
+    level, nxt = np.zeros(1, np.int64), 1
+    while level.size:
+        inner = level[cl[level] >= 0]
+        ids = nxt + 2 * np.arange(inner.size)
+        new[cl[inner]], new[cr[inner]] = ids, ids + 1
+        nxt += 2 * inner.size
+        level = np.stack([cl[inner], cr[inner]], axis=1).ravel()
+    leaf = cl < 0
+    m = cl.shape[0]
+    feat, thresh = np.zeros(m, np.int32), np.full(m, np.inf, np.float32)
+    left = np.arange(m, dtype=np.int32)
+    feat[new[~leaf]] = tree.feature[~leaf]
+    thresh[new[~leaf]] = _f32_at_most(tree.threshold[~leaf])
+    left[new[~leaf]] = new[cl[~leaf]]
+    value = np.zeros((m, tree.value.shape[-1]), np.float32)
+    value[new] = tree.value[:, 0, :]
+    return feat, thresh, left, value
+
+
+def from_sklearn_forest(forest) -> TreeEnsemble:
+    """A fitted scikit-learn tree classifier ensemble (anything whose
+    ``estimators_[i].tree_`` has ``children_left``/``children_right``,
+    ``feature``, ``threshold`` and ``value``) as an ``rf`` ensemble of node
+    arrays, each tree padded to the largest with leaves. Each float64 threshold becomes the
+    largest float32 not above it, so that float32 ``x <= t`` decides as
+    scikit-learn decides on float32 rows; ``leaf`` holds each node's
+    ``value`` (class weights or fractions), whose per-tree normalised mean
+    ``predict_tree_ensemble`` takes. Reads the arrays alone: no
+    scikit-learn import."""
+    trees = [est.tree_ for est in forest.estimators_]
+    parts = [_sklearn_nodes(t) for t in trees]
+    m = max(p[0].shape[0] for p in parts)
+    n_trees, n_classes = len(parts), parts[0][3].shape[1]
+    feat = np.zeros((n_trees, m), np.int32)
+    thresh = np.full((n_trees, m), np.inf, np.float32)
+    left = np.tile(np.arange(m, dtype=np.int32), (n_trees, 1))
+    leaf = np.zeros((n_trees, m, n_classes), np.float32)
+    for t, (f, th, lt, v) in enumerate(parts):
+        k = f.shape[0]
+        feat[t, :k], thresh[t, :k], left[t, :k], leaf[t, :k] = f, th, lt, v
+    return TreeEnsemble(feat=jnp.asarray(feat), thresh=jnp.asarray(thresh),
+                        leaf=jnp.asarray(leaf), kind="rf",
+                        n_classes=n_classes, left=jnp.asarray(left),
+                        depth=max(int(t.max_depth) for t in trees))

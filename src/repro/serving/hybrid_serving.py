@@ -36,6 +36,14 @@ step as a traced argument, so a call moves only its rows to the device
 and a new tau never recompiles. The host counter ``threshold_uploads``
 counts the scalars made.
 
+Backend weights: the step takes the backend as an argument, a
+``jax.tree_util.Partial``. One that carries its arrays as the Partial's
+leaves, such as ``Partial(predict_tree_ensemble, ens)``, has them put on
+the device once, at construction, inside the host span
+``hybrid.backend_put``, and traced: the compiled step holds none of their
+bytes. A plain function is wrapped as a Partial with no leaves, so the
+arrays it closes over lower into the step as constants, as before.
+
 Tracing: every classify opens host spans (``repro.obs.span``) that share
 the call's id, ``hybrid.h2d`` around the transfer of the rows to the
 device and ``hybrid.dispatch`` around the step (on the
@@ -61,10 +69,11 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.tree_util import Partial
 
 from repro.core.artifact import TableArtifact, finalize_artifact
-from repro.core.hybrid import (backend_block, backend_over_blocks, combine,
-                               dispatch, switch_columns)
+from repro.core.hybrid import (backend_block, backend_over_blocks,
+                               combine, dispatch, switch_columns)
 from repro.kernels.ops import fused_classify
 from repro.kernels.tuning import (DEFAULT_TILES, TileConfig, autotune_tiles,
                                   resolve_use_pallas)
@@ -137,7 +146,9 @@ class HybridServer:
         dispatch buffer that holds forwarded rows, and on none when no
         row is forwarded (``core.hybrid.backend_over_blocks``; ``B`` is
         128 where ``capacity`` is a multiple of it, else ``capacity``).
-        The two-phase path hands it the whole (capacity, F) buffer.
+        The two-phase path hands it the whole (capacity, F) buffer. A
+        ``jax.tree_util.Partial`` over a model has its leaves put on the
+        device here and passed to the step as arguments.
 
         switch_features: the column indices of a request row that the
         switch parses, in the artifact's feature order (static: they are
@@ -169,6 +180,15 @@ class HybridServer:
         # (sweeping tau never recompiles); the setter keeps it on the
         # device as the f32[] scalar every classify passes to the step.
         self._backend_fn = backend_fn
+        # the step's backend argument: a Partial's leaves (the forest of
+        # ``Partial(predict_tree_ensemble, ens)``) go to the device once
+        # and are traced; a plain function is a Partial with no leaves
+        backend = (backend_fn if isinstance(backend_fn, Partial)
+                   else Partial(backend_fn))
+        if jax.tree.leaves(backend):
+            with span("hybrid.backend_put"):
+                backend = jax.block_until_ready(jax.device_put(backend))
+        self._backend = backend
         self._capacity = capacity
         self.threshold_uploads = 0              # device scalars made for tau
         self.threshold = threshold
@@ -198,11 +218,11 @@ class HybridServer:
                 rows = jnp.sum(valid.astype(jnp.int32))
             return sw_pred, buf, idx, valid, frac, rows
 
-        def step(art, x, threshold):
+        def step(art, x, threshold, backend=backend):
             sw_pred, buf, idx, valid, frac, rows = switch_only(art, x,
                                                                threshold)
             with jax.named_scope("backend"):
-                be_pred = backend_over_blocks(backend_fn, buf, rows)
+                be_pred = backend_over_blocks(backend, buf, rows)
             with jax.named_scope("combine"):
                 pred = combine(sw_pred, be_pred, idx, valid)
             return pred, frac, rows
@@ -249,7 +269,8 @@ class HybridServer:
         with span("hybrid.dispatch", call=call):
             if self._fused_ok is None:
                 try:
-                    pred, frac, rows = self._step(self.artifact, x, tau)
+                    pred, frac, rows = self._step(self.artifact, x, tau,
+                                                  self._backend)
                     self._fused_ok = True
                     return pred, HybridStats(frac, rows, self.capacity)
                 except (jax.errors.JAXTypeError, TypeError):
@@ -257,7 +278,8 @@ class HybridServer:
                     # any execution
                     self._fused_ok = False
             if self._fused_ok:
-                pred, frac, rows = self._step(self.artifact, x, tau)
+                pred, frac, rows = self._step(self.artifact, x, tau,
+                                              self._backend)
                 return pred, HybridStats(frac, rows, self.capacity)
             # two-phase fallback: untraceable backend runs on host between
             # the jitted switch half and the jitted combine
@@ -279,7 +301,8 @@ class HybridServer:
         timed window."""
         x = jax.ShapeDtypeStruct(
             (n_rows, n_features or self.artifact.n_features), jnp.float32)
-        compiled = self._step.lower(self.artifact, x, self._tau).compile()
+        compiled = self._step.lower(self.artifact, x, self._tau,
+                                    self._backend).compile()
         return op_scopes(compiled.as_text())
 
     def update_tables(self, artifact: TableArtifact):

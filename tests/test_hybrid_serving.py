@@ -560,3 +560,134 @@ def test_the_step_lowers_as_with_a_tau_made_per_call(hybrid_setup):
               for tau in (srv._tau, jnp.float32(0.7),
                           jax.ShapeDtypeStruct((), jnp.float32))}
     assert len(hashes) == 1
+
+
+def _as_node_arrays(ens):
+    """A complete heap forest as node arrays: node ``i``'s children
+    ``2i+1`` and ``2i+2`` held in ``left``, each leaf its own index. It
+    predicts what the heap predicts, through the node walk."""
+    from repro.ml.trees import TreeEnsemble
+    n_trees, h = ens.feat.shape
+    m = 2 * h + 1
+    node = jnp.arange(m, dtype=jnp.int32)
+    pad = (n_trees, h + 1)
+    return TreeEnsemble(
+        feat=jnp.concatenate([ens.feat, jnp.zeros(pad, jnp.int32)], 1),
+        thresh=jnp.concatenate([ens.thresh, jnp.full(pad, jnp.inf)], 1),
+        leaf=jnp.concatenate([jnp.zeros((n_trees, h, ens.leaf.shape[2])),
+                              ens.leaf], 1),
+        kind=ens.kind, n_classes=ens.n_classes,
+        left=jnp.broadcast_to(jnp.where(node < h, 2 * node + 1, node),
+                              (n_trees, m)),
+        depth=ens.depth)
+
+
+@pytest.mark.parametrize("path", ["fused", "two_phase"])
+def test_a_node_array_backend_serves_as_hybrid_serve(hybrid_setup, path):
+    """A backend over node arrays, passed as a pytree
+    (``Partial(predict_tree_ensemble, ens)``), serves bit for bit what
+    ``core.hybrid.hybrid_serve`` answers with the same backend, and what a
+    server over the same forest as a complete heap (a closure) answers."""
+    from jax.tree_util import Partial
+    art, small, big, xte, yte = hybrid_setup
+    nodes = _as_node_arrays(big)
+    assert not nodes.complete
+    backend = Partial(predict_tree_ensemble, nodes)
+    srv = HybridServer(art, backend, threshold=0.95, capacity=256,
+                       fuse=None if path == "fused" else False)
+    heap = HybridServer(art, lambda r: predict_tree_ensemble(big, r),
+                        threshold=0.95, capacity=256)
+    x = xte[:1000]
+    pred, stats = srv.classify(x)
+    assert srv._fused_ok is (path == "fused")
+    assert 0 < stats.backend_rows <= 256
+    want, _ = hybrid_serve(art, backend, x, 0.95, 256)
+    np.testing.assert_array_equal(np.asarray(pred), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(pred),
+                                  np.asarray(heap.classify(x)[0]))
+
+
+def test_a_pytree_backend_is_put_on_the_device_once(hybrid_setup,
+                                                     monkeypatch):
+    """The backend's arrays go to the device at construction, inside the
+    one ``hybrid.backend_put`` span, and every call passes those device
+    arrays to the step; a plain closure has none and opens no span."""
+    import contextlib
+
+    from jax.tree_util import Partial
+
+    import repro.serving.hybrid_serving as hs
+    art, small, big, xte, yte = hybrid_setup
+    events = []
+
+    @contextlib.contextmanager
+    def span(name, **ids):
+        events.append(name)
+        yield
+
+    monkeypatch.setattr(hs, "span", span)
+    nodes = _as_node_arrays(big)
+    host = jax.tree.map(np.asarray, nodes)
+    srv = HybridServer(art, Partial(predict_tree_ensemble, host),
+                       capacity=128)
+    assert events == ["hybrid.backend_put"]
+    leaves = jax.tree.leaves(srv._backend)
+    assert len(leaves) == len(jax.tree.leaves(nodes)) == 4
+    assert all(isinstance(w, jax.Array) for w in leaves)
+    for _ in range(2):
+        srv.classify(xte[:256])
+    assert events.count("hybrid.backend_put") == 1
+    events.clear()
+    plain = HybridServer(art, lambda r: predict_tree_ensemble(big, r),
+                         capacity=128)
+    assert events == [] and jax.tree.leaves(plain._backend) == []
+
+
+def test_a_plain_backend_lowers_as_when_closed_over(hybrid_setup):
+    """A plain function goes to the step as a ``Partial`` with no leaves:
+    passed as the step's argument it lowers to the very text of the step
+    that closes over it (the default), and it keeps its arrays as
+    constants; a ``Partial`` backend is passed as it is, not wrapped."""
+    from jax.tree_util import Partial
+    art, small, big, xte, yte = hybrid_setup
+    x = jax.ShapeDtypeStruct((256, 5), jnp.float32)
+    tau = jnp.float32(0.7)
+    srv = HybridServer(art, _backend(big, "fused"), capacity=128)
+    assert isinstance(srv._backend, Partial)
+    assert srv._backend.func is srv.backend_fn
+    closed = srv._step.lower(srv.artifact, x, tau).as_text()
+    passed = srv._step.lower(srv.artifact, x, tau, srv._backend).as_text()
+    assert passed == closed
+    nodes = Partial(predict_tree_ensemble, _as_node_arrays(big))
+    tree = HybridServer(art, nodes, capacity=128)
+    assert tree._backend.func is predict_tree_ensemble
+    assert (jax.tree.structure(tree._backend)
+            == jax.tree.structure(nodes))
+
+
+def test_the_step_holds_no_constant_of_the_forest(hybrid_setup):
+    """The lowered step of a server over node arrays takes the forest as
+    arguments: its text does not grow with the node count (depth 4 to 8,
+    16 times the nodes), where a closure over the same forests lowers
+    them into the step as constants."""
+    from jax.tree_util import Partial
+    art, small, big, xte, yte = hybrid_setup
+    x = jax.ShapeDtypeStruct((256, 5), jnp.float32)
+    tau = jnp.float32(0.7)
+    forests = [_as_node_arrays(fit_random_forest(
+        xte, yte, n_classes=2, n_trees=30, max_depth=d, seed=1))
+        for d in (4, 8)]
+    sizes = [sum(a.nbytes for a in jax.tree.leaves(f)) for f in forests]
+    assert sizes[1] > 10 * sizes[0]
+
+    def text(backend):
+        srv = HybridServer(art, backend, capacity=128)
+        return srv._step.lower(srv.artifact, x, tau,
+                               srv._backend).as_text()
+
+    args = [text(Partial(predict_tree_ensemble, f)) for f in forests]
+    consts = [text(lambda r, f=f: predict_tree_ensemble(f, r))
+              for f in forests]
+    assert abs(len(args[1]) - len(args[0])) < 64
+    assert len(consts[1]) - len(consts[0]) > sizes[1] - sizes[0]
+    assert len(args[1]) < len(consts[1]) - sizes[1]

@@ -193,3 +193,33 @@ def test_chunk_megastep_compiles_with_both_kernels(chip, flow_models,
     _assert_kernel(compiled.as_text(), count=2)
     # the register file is donated and aliased in place
     assert compiled.memory_analysis().alias_size_in_bytes >= 8 * N_BUCKETS * 4
+
+
+def test_a_grown_forest_backend_compiles_as_step_arguments(chip, artifacts,
+                                                           monkeypatch):
+    """The finance per-request step with the benchmark's fully grown
+    backend shape (100 trees of node arrays padded to 32,768 nodes, 52
+    levels, on 130-feature rows) compiles for the chip with the fused
+    classify kernel, and takes the forest's 65.5 MB as arguments: the
+    compiled program's own code stays a small fraction of it."""
+    from jax.tree_util import Partial
+
+    from repro.ml.trees import TreeEnsemble, predict_tree_ensemble
+    from repro.serving.hybrid_serving import HybridServer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n_trees, m = 100, 1 << 15
+    ens = TreeEnsemble(feat=np.zeros((n_trees, m), np.int32),
+                       thresh=np.zeros((n_trees, m), np.float32),
+                       leaf=np.zeros((n_trees, m, 2), np.float32),
+                       left=np.zeros((n_trees, m), np.int32), depth=52)
+    art, rows = artifacts["finance_rf10x5"]
+    srv = HybridServer(art, Partial(predict_tree_ensemble, ens),
+                       capacity=1024, switch_features=SWITCH_FEATURES)
+    compiled = srv._step.lower(
+        jax.tree.map(chip, srv.artifact), chip((rows, 130)),
+        chip((), jnp.float32), jax.tree.map(chip, srv._backend)).compile()
+    _assert_kernel(compiled.as_text())
+    mem = compiled.memory_analysis()
+    forest = n_trees * m * 20
+    assert mem.argument_size_in_bytes >= forest
+    assert mem.generated_code_size_in_bytes < forest // 4

@@ -1,8 +1,11 @@
-"""The two forest walks of ``repro.ml.trees``: level-wise selects for trees
-up to ``SELECT_MAX_DEPTH`` deep, the heap walk beyond. Both must give the
-same leaf indices bit for bit, so every prediction built on them is the
-same; the heap walk stays in the module, so the tests compare against it
-directly (``SELECT_MAX_DEPTH`` set to 0 routes every tree through it)."""
+"""The two forest walks of ``repro.ml.trees``: level-wise selects for
+complete trees up to ``SELECT_MAX_DEPTH`` deep, the node walk for every
+other ensemble (complete heaps deeper than that, with implicit children,
+and node arrays of any shape, with explicit ones). On complete trees both
+must give the same leaf indices bit for bit, so every prediction built on
+them is the same; ``SELECT_MAX_DEPTH`` set to 0 routes every tree through
+the node walk, so the tests compare the two directly. Node arrays (fully
+grown forests, scikit-learn's) are checked against a numpy walk."""
 
 import dataclasses
 
@@ -69,7 +72,7 @@ def _scores(ens, x):
 
 def _both_walks(ens, x, monkeypatch):
     """(leaf indices, scores, predictions) on the selects, then on the
-    heap walk."""
+    node walk."""
     out = []
     for bound in (trees.SELECT_MAX_DEPTH, 0):
         monkeypatch.setattr(trees, "SELECT_MAX_DEPTH", bound)
@@ -170,7 +173,7 @@ def _gathers(ens, n_rows):
 def test_backend_shape_takes_the_gather_free_walk():
     """The benchmark's backend shape (32 trees of depth 8 on 1,024 rows of
     5 features) traces to no gather at all; one level past the bound the
-    same forest takes the heap walk and its gathers."""
+    same forest takes the node walk and its gathers."""
     assert _gathers(_dense_forest(8, n_trees=32), 1024) == 0
     deep = _dense_forest(trees.SELECT_MAX_DEPTH + 1, n_trees=32)
     assert _gathers(deep, 1024) > 0
@@ -221,3 +224,148 @@ def test_boosted_margins_in_row_blocks_match_a_numpy_heap_walk(monkeypatch):
     sure = np.abs(want) > band
     assert sure.mean() > 0.9 and 0 < pred[sure].mean() < 1
     np.testing.assert_array_equal(pred[sure], (want > 0)[sure])
+
+
+def _grown_forest(n_trees=8, n_rows=2000, n_feat=N_FEAT, seed=0):
+    """(forest as node arrays, training rows): fully grown random-split
+    trees on noisy labels. A node whose rows hold both classes splits on
+    a random feature at a uniform threshold between its rows' extremes
+    (a leaf where that feature is constant); nodes are numbered breadth
+    first, each split's children side by side; trees are padded to the
+    largest with leaves."""
+    import collections
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(n_rows, n_feat)).astype(np.float32)
+    y = (x[:, 0] + 1.5 * rng.normal(size=n_rows) > 0).astype(np.int64)
+    trees, depth = [], 0
+    for _ in range(n_trees):
+        feat, thresh, left, leaf = [0], [np.inf], [0], [None]
+        queue = collections.deque([(0, np.arange(n_rows), 0)])
+        while queue:
+            node, rows, d = queue.popleft()
+            leaf[node] = np.bincount(y[rows], minlength=2)
+            depth = max(depth, d)
+            f = int(rng.integers(n_feat))
+            lo, hi = x[rows, f].min(), x[rows, f].max()
+            if leaf[node].min() == 0 or lo == hi:
+                left[node] = node
+                continue
+            t = min(np.float32(rng.uniform(lo, hi)),
+                    np.nextafter(hi, np.float32(-np.inf)))
+            feat[node], thresh[node], left[node] = f, t, len(feat)
+            go = x[rows, f] <= t
+            for part in (rows[go], rows[~go]):
+                queue.append((len(feat), part, d + 1))
+                feat.append(0), thresh.append(np.inf)
+                left.append(len(left)), leaf.append(None)
+        trees.append((feat, thresh, left, leaf))
+    m = max(len(t[0]) for t in trees)
+    arr = {k: np.zeros((n_trees, m), np.int32) for k in ("feat", "left")}
+    arr["left"][:] = np.arange(m)
+    arr["thresh"] = np.full((n_trees, m), np.inf, np.float32)
+    arr["leaf"] = np.zeros((n_trees, m, 2), np.float32)
+    for i, (feat, thresh, left, leaf) in enumerate(trees):
+        k = len(feat)
+        arr["feat"][i, :k], arr["thresh"][i, :k] = feat, thresh
+        arr["left"][i, :k], arr["leaf"][i, :k] = left, np.stack(leaf)
+    ens = TreeEnsemble(feat=jnp.asarray(arr["feat"]),
+                       thresh=jnp.asarray(arr["thresh"]),
+                       leaf=jnp.asarray(arr["leaf"]), kind="rf",
+                       n_classes=2, left=jnp.asarray(arr["left"]),
+                       depth=depth)
+    return ens, x
+
+
+def _numpy_node_walk(ens, x):
+    """(T, N) leaf node per tree, walked until the node is its own
+    ``left``, with float32 compares (``x > t`` goes right)."""
+    feat, thresh, left = (np.asarray(a) for a in (ens.feat, ens.thresh,
+                                                  ens.left))
+    out = np.empty((feat.shape[0], len(x)), np.int64)
+    for t in range(feat.shape[0]):
+        node = np.zeros(len(x), np.int64)
+        for _ in range(feat.shape[1]):
+            inner = left[t, node] != node
+            if not inner.any():
+                break
+            step = left[t, node] + (x[np.arange(len(x)), feat[t, node]]
+                                    > thresh[t, node])
+            node = np.where(inner, step, node)
+        out[t] = node
+    return out
+
+
+@pytest.mark.parametrize("bound", [trees.SELECT_MAX_DEPTH, 0])
+def test_node_walk_matches_a_numpy_walk_on_grown_forests(monkeypatch,
+                                                          bound):
+    """Eight fully grown trees on 2,000 rows, 20 or more levels deep: the
+    node walk's leaves are a numpy walk's, whatever ``SELECT_MAX_DEPTH``
+    says (node arrays never take the selects); every training row lands
+    in a pure leaf of its own class; the rf answer is the class of
+    highest mean leaf fraction, the first on a tie."""
+    monkeypatch.setattr(trees, "SELECT_MAX_DEPTH", bound)
+    ens, xtr = _grown_forest()
+    assert ens.depth >= 20 and not ens.complete
+    x = _probe_rows(ens, np.concatenate([xtr[:500], np.random.default_rng(
+        1).normal(size=(500, N_FEAT)).astype(np.float32)]))
+    idx = np.asarray(tree_leaf_indices(ens, x))
+    want = _numpy_node_walk(ens, x)
+    np.testing.assert_array_equal(idx, want)
+    leaf = np.asarray(ens.leaf).astype(np.float64)
+    frac = np.stack([leaf[t, want[t]] for t in range(ens.n_trees)])
+    frac = (frac / frac.sum(-1, keepdims=True)).mean(axis=0)
+    sure = np.abs(frac[:, 0] - frac[:, 1]) > 1e-6
+    pred = np.asarray(predict_tree_ensemble(ens, x))
+    np.testing.assert_array_equal(pred[sure], frac.argmax(axis=1)[sure])
+    train = np.stack([leaf[t, want[t, :500]] for t in range(ens.n_trees)])
+    assert (train.min(axis=-1) == 0).all()               # pure leaves
+
+
+def test_node_arrays_need_their_depth():
+    ens, _ = _grown_forest(n_trees=1, n_rows=50)
+    with pytest.raises(ValueError, match="depth"):
+        TreeEnsemble(feat=ens.feat, thresh=ens.thresh, leaf=ens.leaf,
+                     left=ens.left)
+
+
+def test_sklearn_thresholds_round_down_to_float32():
+    """Each float64 threshold becomes the largest float32 not above it:
+    a float32 row decides ``x <= t`` as against the float64 value, on
+    either side of every rounding."""
+    rng = np.random.default_rng(0)
+    t64 = np.concatenate([rng.normal(size=1000),
+                          rng.normal(size=1000).astype(np.float32)
+                          .astype(np.float64), [0.0, -0.0, 1e-300]])
+    t32 = trees._f32_at_most(t64)
+    assert t32.dtype == np.float32 and (t32.astype(np.float64) <= t64).all()
+    up = np.nextafter(t32, np.float32(np.inf))
+    assert (up.astype(np.float64) > t64).all()
+    for x in (t32, up, np.nextafter(t32, np.float32(-np.inf))):
+        np.testing.assert_array_equal(x <= t32,
+                                      x.astype(np.float64) <= t64)
+
+
+@pytest.mark.parametrize("n_estimators", [1, 8])
+def test_from_sklearn_forest_predicts_as_extra_trees(n_estimators):
+    """A fitted ``ExtraTreesClassifier`` read as node arrays walks to the
+    leaves scikit-learn's ``apply`` finds and predicts what its
+    ``predict`` predicts, on held-out float32 rows."""
+    ensemble = pytest.importorskip("sklearn.ensemble")
+    from repro.ml.trees import from_sklearn_forest
+    rng = np.random.default_rng(3)
+    x = rng.normal(size=(3000, 10)).astype(np.float32)
+    y = (x[:, 0] + x[:, 1] * x[:, 2] + rng.normal(size=3000) > 0)
+    clf = ensemble.ExtraTreesClassifier(n_estimators=n_estimators,
+                                        random_state=0).fit(x[:2000], y[:2000])
+    ens = from_sklearn_forest(clf)
+    assert not ens.complete and ens.n_trees == n_estimators
+    assert ens.depth == max(e.tree_.max_depth for e in clf.estimators_)
+    xte = x[2000:]
+    idx = np.asarray(tree_leaf_indices(ens, xte))
+    leaf = np.asarray(ens.leaf)
+    for t, est in enumerate(clf.estimators_):    # the same leaf's value
+        np.testing.assert_array_equal(
+            leaf[t, idx[t]], est.tree_.value[est.apply(xte), 0, :]
+            .astype(np.float32))
+    np.testing.assert_array_equal(np.asarray(predict_tree_ensemble(ens, xte)),
+                                  clf.predict(xte).astype(np.int32))
